@@ -31,6 +31,8 @@ import json, sys, time
 import numpy as np
 import jax, jax.numpy as jnp
 from repro import compat
+from repro.launch.compile_cache import enable_compile_cache
+enable_compile_cache()
 from repro.coded import CodedMatmulConfig, from_plan
 from repro.core.coded_matmul import make_plan, uncoded_matmul_reference
 from repro.sparse import dense_to_block_ell

@@ -4,11 +4,11 @@ Benchmarks the coded local product at the KERNEL level (no mesh, no psum):
 ``spmm_block_fused_decode`` (one launch, decode combine in the epilogue)
 against the historical two-step path (local product launch, then the
 decode broadcast-multiply as a second launch), on whatever lane
-``resolve_lane`` picks for this host -- XLA on CPU CI, Pallas-Triton on
-GPU, compiled Pallas on TPU.  Results are reported as FRACTION of this
-machine's calibrated roofline (``repro.launch.roofline.machine_peaks``),
-not just wall-clock, so a number from the CPU CI box and a number from a
-GPU runner mean the same thing.  Quantized packs (bf16 / int8 tile values,
+``resolve_lane`` picks for this host -- XLA on CPU CI, compiled Pallas on
+TPU.  Results are reported as FRACTION of a roofline
+(``repro.launch.roofline.machine_peaks``): the chip's published peaks on a
+TPU, in-place calibrated peaks on CPU -- a CPU fraction is not a device
+number.  Quantized packs (bf16 / int8 tile values,
 weights exact) ride along as a dtype sweep of the fused kernel.
 
 Persists the ``kernel`` key of BENCH_coded_matmul.json.
@@ -30,7 +30,8 @@ import json, sys, time
 import numpy as np
 import jax, jax.numpy as jnp
 
-jax.devices()  # pin the backend BEFORE roofline's XLA_FLAGS import hook
+from repro.launch.compile_cache import enable_compile_cache
+enable_compile_cache()
 from repro.launch.roofline import machine_peaks, fused_kernel_cost, roofline_fraction
 from repro.kernels import ops
 from repro.kernels.spmm_block import resolve_lane
@@ -61,7 +62,7 @@ def bench(fn, *args, reps=20):
     return float(np.median(ts))
 
 lane = resolve_lane()
-peaks = machine_peaks()
+peaks = machine_peaks(calibrate_cpu=jax.default_backend() == "cpu")
 
 # two launches: the local product, then the decode combine as its own jit
 # (a launch boundary, exactly what the staged program used to pay)

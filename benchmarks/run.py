@@ -13,6 +13,8 @@ import argparse
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from benchmarks import (
     bench_chaos,
     bench_completion,
@@ -46,6 +48,7 @@ def main(argv=None) -> None:
                       help="small configurations (the default, made explicit)")
     ap.add_argument("--only", default=None, choices=list(SUITES))
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     names = [args.only] if args.only else list(SUITES)
     failed = []

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.training import checkpoint as ckpt_lib
 from repro.training.data import SyntheticCorpus
@@ -22,6 +23,7 @@ from repro.training.train_step import make_train_step
 
 
 def main():
+    enable_compile_cache()
     cfg = configs.get("internlm2-1.8b").reduced()
     model = build(cfg)
     params = model.init(jax.random.key(0), jnp.float32)

@@ -16,6 +16,7 @@ least one straggler recovery was recorded.
 import jax
 
 from repro.configs import ARCH_REGISTRY
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.chaos import kill
 from repro.runtime.procpool import MuxProcPool
 from repro.serving import SLO, TenantSpec, poisson_trace
@@ -26,6 +27,7 @@ NUM_WORKERS = 6
 
 def main():
     assert jax.default_backend() == "cpu", "demo is a CPU smoke"
+    enable_compile_cache()
     cfg = ARCH_REGISTRY["qwen3-moe-30b-a3b"].reduced()
     tenants = [
         TenantSpec("interactive", rate=25.0, prompt_len=6, max_new_tokens=2,

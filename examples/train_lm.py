@@ -18,6 +18,7 @@ import sys
 sys.path.insert(0, "src")
 
 from repro.configs.base import ArchConfig, register
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import main as train_main
 
 LM_100M = register(ArchConfig(
@@ -34,6 +35,7 @@ LM_100M = register(ArchConfig(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--steps", type=int, default=300)

@@ -35,14 +35,14 @@ import numpy as np
 
 from repro.analysis.findings import ERROR, WARNING, Finding
 
-#: collectives whose axis names the staged program must get right (psum2 is
-#: the spelling shard_map emits when tracing over an AbstractMesh)
-_COLLECTIVE_PRIMS = ("psum", "psum2", "reduce_scatter", "psum_scatter",
+#: collectives whose axis names the staged program must get right
+#: (psum_invariant is the spelling shard_map emits under check_vma=True)
+_COLLECTIVE_PRIMS = ("psum", "psum_invariant", "reduce_scatter", "psum_scatter",
                      "all_gather", "all_to_all", "ppermute")
 
 
 def _sub_jaxprs(val) -> Iterator:
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     if isinstance(val, ClosedJaxpr):
         yield val.jaxpr

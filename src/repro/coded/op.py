@@ -38,6 +38,7 @@ from repro.core.coded_matmul import (
     WorkerTilePack,
     _check_operands,
     chunk_mask_progress,
+    lower_coded_matmul,
     resolve_pack,
     stage_coded_matmul,
 )
@@ -160,21 +161,9 @@ class CodedOp:
                   else "dense_scan")
         return chosen, frac, a_sparse
 
-    def apply(self, A, B, *, a_sparse: BlockELL | None = None,
-              pack: WorkerTilePack | None = None):
-        """C = A^T B under this op's code, config, and survivor mask.
-
-        For pack-consuming backends (``block_sparse``), pass ``a_sparse``
-        (a host BlockELL of A -- packed once and memoized via the runtime
-        pack cache) or ``pack`` (a prebuilt ``WorkerTilePack``); a concrete
-        (non-traced) A is packed automatically with ``config.block_size``.
-        Backends that take no pack reject these operands outright instead
-        of silently ignoring them.  ``backend="auto"`` measures the
-        operand's live-tile fraction against
-        ``config.auto_density_threshold`` and dispatches to block_sparse
-        (sparse enough) or dense_scan; the density inputs are consumed by
-        that decision and simply dropped when dense_scan wins.
-        """
+    def _staging_kwargs(self, A, B, a_sparse, pack) -> dict:
+        """Resolve backend and tile pack for ``A``, ``B``: the keyword
+        arguments of ``stage_coded_matmul`` / ``lower_coded_matmul``."""
         if self.mesh is None:
             raise ValueError(
                 "unbound CodedOp: call .bind(mesh) (or .bind()) first")
@@ -198,14 +187,41 @@ class CodedOp:
                 A, self.base_plan, pack=pack, a_sparse=a_sparse,
                 block_size=cfg.block_size, compute_dtype=cfg.compute_dtype,
                 num_workers=N, s=s, r=r, br=br)
+        return dict(axis_name=cfg.axis_name, alive=self.survivors,
+                    out_dtype=cfg.np_dtype, backend=backend, pack=pack,
+                    out_sharded=cfg.out_sharded)
+
+    def apply(self, A, B, *, a_sparse: BlockELL | None = None,
+              pack: WorkerTilePack | None = None):
+        """C = A^T B under this op's code, config, and survivor mask.
+
+        For pack-consuming backends (``block_sparse``), pass ``a_sparse``
+        (a host BlockELL of A -- packed once and memoized via the runtime
+        pack cache) or ``pack`` (a prebuilt ``WorkerTilePack``); a concrete
+        (non-traced) A is packed automatically with ``config.block_size``.
+        Backends that take no pack reject these operands outright instead
+        of silently ignoring them.  ``backend="auto"`` measures the
+        operand's live-tile fraction against
+        ``config.auto_density_threshold`` and dispatches to block_sparse
+        (sparse enough) or dense_scan; the density inputs are consumed by
+        that decision and simply dropped when dense_scan wins.
+        """
         return stage_coded_matmul(
             A, B, self.plan_, self.mesh,
-            axis_name=cfg.axis_name,
-            alive=self.survivors,
-            out_dtype=cfg.np_dtype,
-            backend=backend,
-            pack=pack,
-            out_sharded=cfg.out_sharded)
+            **self._staging_kwargs(A, B, a_sparse, pack))
+
+    def lower(self, A, B, *, a_sparse: BlockELL | None = None,
+              pack: WorkerTilePack | None = None):
+        """``jax.stages.Lowered`` of the program ``apply`` would run.
+
+        ``A`` and ``B`` may be ``jax.ShapeDtypeStruct``s when ``pack`` (or,
+        for dense_scan, nothing) is given, so ``.compile()`` can target a
+        described topology; ``.compile().as_text()`` shows the kernels and
+        collectives, ``memory_analysis()`` the bytes per device.
+        """
+        return lower_coded_matmul(
+            A, B, self.plan_, self.mesh,
+            **self._staging_kwargs(A, B, a_sparse, pack))
 
     __call__ = apply
 

@@ -1,116 +1,42 @@
-"""JAX version-compatibility layer (DESIGN.md section 4).
+"""JAX API boundary (DESIGN.md section 4).
 
-Policy: every JAX API whose location or signature changed across the
-versions we support is accessed ONLY through this module.  Call sites never
-touch ``jax.shard_map`` / ``jax.experimental.shard_map`` /
-``jax.sharding.AxisType`` / ``jax.make_mesh(..., axis_types=...)``
-directly -- that rule is what lets a single checkout run on the whole
-support matrix:
+Policy: every JAX API whose location or signature has moved between JAX
+releases is reached ONLY through this module.  Call sites never touch
+``jax.shard_map`` / ``jax.sharding.AxisType`` / ``jax.make_mesh`` /
+``jax.lax.psum_scatter`` directly (the ``compat-boundary`` lint enforces
+it), so the next upgrade that moves one of them is a one-file change.
 
-  =============  =====================================  ==================
-  JAX            shard_map                              AxisType / mesh
-  =============  =====================================  ==================
-  0.4.35-0.4.x   jax.experimental.shard_map(check_rep)  no AxisType; plain
-                                                        jax.make_mesh
-  0.5.x-0.6.x    jax.experimental (top-level appears    AxisType appears;
-                 late in the range)                     axis_types kwarg
-  >= 0.7         jax.shard_map(check_vma)               jax.sharding.AxisType
-  =============  =====================================  ==================
-
-``shard_map`` here accepts BOTH spellings of the replication-check flag
-(``check_vma`` is the new name of ``check_rep``) and forwards whichever one
-the installed JAX understands.  ``AxisType`` is the real enum when present
-and an inert stand-in otherwise (on old JAX every mesh axis behaves as
-Auto, so dropping the annotation is semantically a no-op).
+The tree supports exactly the JAX pinned in ``requirements.txt`` (0.9.0);
+there are no branches for older releases.
 """
 
 from __future__ import annotations
 
-import enum
-import inspect
-
 import jax
 
-JAX_VERSION: tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit()
-)
-
-# --------------------------------- shard_map --------------------------------
-
-_SHARD_MAP = getattr(jax, "shard_map", None)
-if _SHARD_MAP is None:
-    from jax.experimental.shard_map import shard_map as _SHARD_MAP
-# decide the flag spelling by signature, not by where the function lives:
-# the top-level export appeared before the check_rep -> check_vma rename
-_SHARD_MAP_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_SHARD_MAP).parameters
-    else "check_rep"
-)
+AxisType = jax.sharding.AxisType
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, check_rep=None,
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
               **kwargs):
-    """Version-portable ``shard_map``.
-
-    ``check_vma`` (new spelling) and ``check_rep`` (old spelling) are
-    aliases for the same replication check; pass at most one.
-    """
-    if check_vma is not None and check_rep is not None and check_vma != check_rep:
-        raise ValueError(
-            f"check_vma={check_vma} and check_rep={check_rep} disagree; "
-            "they are two spellings of the same flag")
-    check = check_vma if check_vma is not None else check_rep
-    if check is None:
-        check = True
-    kwargs[_SHARD_MAP_CHECK_KW] = check
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+    """``jax.shard_map`` (``check_vma`` is its replication check)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma, **kwargs)
 
 
 def psum_scatter(x, axis_name, *, scatter_dimension: int = 0, tiled: bool = True):
-    """Version-portable ``lax.psum_scatter`` (reduce-scatter over a mesh axis).
+    """``lax.psum_scatter`` (reduce-scatter over a mesh axis).
 
-    The decode-sharding path goes through here per the module policy: the
-    collective has lived at ``jax.lax.psum_scatter`` since 0.2.x, but routing
-    it through compat keeps call sites insulated if the signature moves the
-    way shard_map's did.  ``tiled=True`` splits ``scatter_dimension`` (which
-    must divide by the axis size) instead of adding a leading axis.
+    ``tiled=True`` splits ``scatter_dimension`` (which must divide by the
+    axis size) instead of adding a leading axis.
     """
     return jax.lax.psum_scatter(
         x, axis_name, scatter_dimension=scatter_dimension, tiled=tiled)
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized across JAX versions.
-
-    Old JAX (<= 0.4.x) returns a one-element list of per-program dicts; new
-    JAX returns the dict itself.  Always returns a dict (empty if absent).
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
-# ----------------------------- AxisType / meshes ----------------------------
-
-_REAL_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-if _REAL_AXIS_TYPE is not None:  # pragma: no cover - new JAX only
-    AxisType = _REAL_AXIS_TYPE
-else:
-    class AxisType(enum.Enum):
-        """Stand-in for ``jax.sharding.AxisType`` on JAX < 0.5.
-
-        Old JAX has no explicit-sharding axis types: every mesh axis is
-        implicitly Auto, so carrying the annotation (and dropping it at the
-        ``make_mesh`` boundary) preserves semantics.
-        """
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+    """``Compiled.cost_analysis()`` as a dict (empty if the backend has none)."""
+    return compiled.cost_analysis() or {}
 
 
 def auto_axis_types(n: int) -> tuple:
@@ -118,29 +44,14 @@ def auto_axis_types(n: int) -> tuple:
     return (AxisType.Auto,) * n
 
 
-_MAKE_MESH = getattr(jax, "make_mesh", None)
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    _MAKE_MESH is not None
-    and "axis_types" in inspect.signature(_MAKE_MESH).parameters
-)
-
-
 def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` that tolerates the ``axis_types`` kwarg everywhere.
+    """``jax.make_mesh`` over ``devices`` (default: every visible device).
 
-    On JAX without axis types the annotation is dropped (see ``AxisType``);
-    on JAX without ``jax.make_mesh`` at all, the mesh is assembled from
-    ``mesh_utils.create_device_mesh``.
+    Axes default to ``AxisType.Auto``: ``jax.make_mesh`` itself defaults to
+    Explicit axes, under which the eager block->C assembly after a
+    reduce-scatter decode must spell out every output sharding.
     """
-    if _MAKE_MESH is not None:
-        kwargs = {}
-        if devices is not None:
-            kwargs["devices"] = devices
-        if axis_types is not None and _MAKE_MESH_HAS_AXIS_TYPES:
-            kwargs["axis_types"] = axis_types  # pragma: no cover - new JAX
-        return _MAKE_MESH(tuple(axis_shapes), tuple(axis_names), **kwargs)
-    from jax.experimental import mesh_utils  # pragma: no cover - old JAX
-
-    dev_mesh = mesh_utils.create_device_mesh(  # pragma: no cover
-        tuple(axis_shapes), devices=devices)
-    return jax.sharding.Mesh(dev_mesh, tuple(axis_names))  # pragma: no cover
+    if axis_types is None:
+        axis_types = auto_axis_types(len(axis_names))
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=axis_types, devices=devices)

@@ -30,10 +30,13 @@ class Backend:
     needs_pack: whether the backend consumes host-side static pack metadata
     (a ``WorkerTilePack``) that must be built outside jit.
     local_product_factory: attached by the implementing module; called as
-    ``factory(plan, pack, bt) -> (k, A, B) -> (br, bt)`` at staging time.
+    ``factory(plan, pack, bt) -> (arrays, fn)`` at staging time, where
+    ``arrays`` are host arrays with a leading worker axis N (the program
+    takes them sharded by worker) and ``fn(A, B, *arrays_k) -> (br, bt)``
+    is worker k's local product on its rows of them.
     fused_decode: the backend can fold the decode combine into its local
     product's epilogue -- staging then calls ``fused_local_product_factory``
-    (``factory(plan, pack, bt) -> (k, A, B, dvec) -> (mn, br, bt)``) and the
+    (same contract, ``fn(A, B, dvec, *arrays_k) -> (mn, br, bt)``) and the
     separate ``D @ C~`` contraction never appears in the staged program.
     virtual: a dispatch pseudo-backend (e.g. ``"auto"``) that the API layer
     resolves to a concrete backend before staging; staging itself rejects it.

@@ -281,6 +281,7 @@ def _local_dense_scan(A, B, cols_k, w_k, m: int, n: int):
         Ai = jax.lax.dynamic_slice(A, (0, i * br), (s, br))
         Bj = jax.lax.dynamic_slice(B, (0, j * bt), (s, bt))
         prod = jnp.einsum("sr,st->rt", Ai, Bj,
+                          precision=jax.lax.Precision.HIGHEST,
                           preferred_element_type=jnp.float32)
         return acc + w.astype(jnp.float32) * prod, None
 
@@ -413,54 +414,34 @@ def pack_worker_tiles(a_sparse: BlockELL, plan: CodedMatmulPlan,
 
 # ------------------------------- entry point --------------------------------
 
-def _largest_tile(bt: int, cap: int = 128) -> int:
-    """Largest divisor of bt that is <= cap (tile width for the kernel grid).
-
-    Falling back to the whole row (bt) only when bt itself is <= cap or
-    prime beyond it -- never a degenerate full-width tile when a proper
-    divisor exists.
-    """
-    for d in range(min(bt, cap), 0, -1):
-        if bt % d == 0:
-            return d
-    return 1
-
-
-def _plan_t_tiling(bt: int, cap: int = 128) -> tuple[int, int]:
+def _plan_t_tiling(bt: int, lane: str) -> tuple[int, int]:
     """(t_tile, bt_pad) for the kernel grid over a bt-wide column group.
 
-    A ``bt`` whose only divisors <= cap are tiny (prime bt, or 2 * prime
-    beyond the cap) used to silently degrade toward t_tile=1 -- a
-    grid-per-element launch.  Instead the column group is zero-padded up to
-    the next multiple of 8 (the VPU sublane) that tiles well, and the
-    caller slices the pad columns back off; zero columns contribute
-    nothing, so the kept columns are bitwise unchanged.
+    The TPU lane tiles the column group in 128-wide tiles, the lane width
+    Mosaic requires of a block's last dimension; a ``bt`` that is not a
+    multiple of 128 is zero-padded up to one, and the caller slices the pad
+    columns back off.  Zero columns contribute nothing, so the kept columns
+    are bitwise unchanged.  The XLA lane does not tile.
     """
-    t_tile = _largest_tile(bt, cap)
-    if t_tile >= min(bt, 8):
-        return t_tile, bt
-    bt_pad = -(-bt // 8) * 8
-    return _largest_tile(bt_pad, cap), bt_pad
+    if lane == "xla":
+        return bt, bt
+    return 128, -(-bt // 128) * 128
 
 
 def _make_dense_scan_local_product(plan: CodedMatmulPlan, pack, bt: int):
-    cols_t = jnp.asarray(plan.cols)        # (N, L)
-    w_t = jnp.asarray(plan.weights)        # (N, L)
     m, n = plan.m, plan.n
 
-    def local_product(k, A_, B_):
-        return _local_dense_scan(A_, B_, cols_t[k], w_t[k], m, n)
+    def local_product(A_, B_, cols_k, w_k):
+        return _local_dense_scan(A_, B_, cols_k, w_k, m, n)
 
-    return local_product
+    return (plan.cols, plan.weights), local_product
 
 
 def _block_sparse_operands(plan: CodedMatmulPlan, pack: WorkerTilePack,
                            bt: int):
-    """Shared staging of the block_sparse factories: device-resident pack
-    arrays, the slot-weight gather, and the (t_tile, bt_pad) grid plan."""
-    vals_t = jnp.asarray(pack.vals)    # (N, CBl, Lw, bs, bs)
-    src_t = jnp.asarray(pack.src)      # (N, CBl, Lw, 2)
-    t_tile, bt_pad = _plan_t_tiling(bt)
+    """Shared staging of the block_sparse factories: the per-worker pack
+    arrays (leading axis N), the kernel lane, and its (t_tile, bt_pad) grid
+    plan with the matching column padding of B."""
     if pack.slot_of is None:
         # a pack without the tile->slot map cannot follow a chunk-masked
         # plan's weights; computing with its baked-in base weights would be
@@ -468,26 +449,27 @@ def _block_sparse_operands(plan: CodedMatmulPlan, pack: WorkerTilePack,
         raise ValueError(
             "WorkerTilePack has no slot_of map (built by a pre-chunking "
             "packer?); rebuild it with pack_worker_tiles")
+    lane = ops.resolve_lane()
+    t_tile, bt_pad = _plan_t_tiling(bt, lane)
     # The pack carries the BASE task table's weights; the staged plan may
     # have zeroed some (chunk-prefix masking).  Re-read each live tile's
     # weight from the *current* plan through slot_of so one pack serves
     # every chunk-progress rebind; for an unmasked plan this reproduces
     # pack.wslot bit-for-bit (same f32 values, gathered instead of copied).
-    w_cur = jnp.asarray(plan.weights)                    # (N, L)
-    sl_t = jnp.asarray(pack.slot_of)                     # (N, CBl, Lw)
-    live_t = jnp.asarray(pack.wslot != 0.0)
     N_ = plan.weights.shape[0]
-    wsl_all = jnp.where(
-        live_t, w_cur[jnp.arange(N_)[:, None, None], sl_t], 0.0)
+    wsl_all = np.where(
+        pack.wslot != 0.0,
+        plan.weights[np.arange(N_)[:, None, None], pack.slot_of],
+        np.float32(0.0))
     if pack.tile_scale is not None:
         # int8 pack: fold the per-tile dequant scale into the per-tile
         # weight -- w * (scale * tile_q) == (w * scale) * tile_q, and the
         # kernels already multiply by the weight, so dequantize is free
-        wsl_all = wsl_all * jnp.asarray(pack.tile_scale)
+        wsl_all = wsl_all * pack.tile_scale
 
     def pad_cols(B_):
         # zero-pad each bt-wide column group up to bt_pad (no-op pass-through
-        # when bt tiles fine); the kernel output is sliced back below
+        # when bt tiles fine); the kernel output is sliced back by the caller
         if bt_pad == bt:
             return B_
         s_, t_ = B_.shape
@@ -495,43 +477,45 @@ def _block_sparse_operands(plan: CodedMatmulPlan, pack: WorkerTilePack,
             B_.reshape(s_, t_ // bt, bt),
             ((0, 0), (0, 0), (0, bt_pad - bt))).reshape(s_, -1)
 
-    return vals_t, src_t, wsl_all, t_tile, bt_pad, pad_cols
+    arrays = (pack.vals, pack.src, wsl_all.astype(np.float32))
+    return arrays, lane, t_tile, bt_pad, pad_cols
 
 
 def _make_block_sparse_local_product(plan: CodedMatmulPlan, pack: WorkerTilePack,
                                      bt: int):
-    vals_t, src_t, wsl_all, t_tile, bt_pad, pad_cols = _block_sparse_operands(
+    arrays, lane, t_tile, bt_pad, pad_cols = _block_sparse_operands(
         plan, pack, bt)
 
-    def local_product(k, A_, B_):
+    def local_product(A_, B_, vals, src, wsl):
         # fused gather: tiles address the original B directly -- no
         # stacked (max_degree * s, bt) copy is ever materialized
-        out = ops.spmm_block_fused(vals_t[k], src_t[k], wsl_all[k],
-                                   pad_cols(B_), bt=bt_pad, t_tile=t_tile)
+        out = ops.spmm_block_fused(vals, src, wsl, pad_cols(B_), bt=bt_pad,
+                                   t_tile=t_tile, lane=lane)
         return out[:, :bt] if bt_pad != bt else out
 
-    return local_product
+    return arrays, local_product
 
 
 def _make_block_sparse_fused_decode(plan: CodedMatmulPlan, pack: WorkerTilePack,
                                     bt: int):
     """The one-launch local product: decode combine fused into the epilogue.
 
-    Returns ``(k, A, B, dvec) -> (mn, br, bt)`` where dvec is this worker's
-    survivor decode column ``D[:, k] * alive_k``; the output is already the
-    stack of decode-weighted copies, ready for the psum -- the separate
-    ``D @ C~`` contraction never exists in the staged program.
+    Returns ``(arrays, fn)``: ``fn(A, B, dvec, *arrays_k) -> (mn, br, bt)``
+    takes worker k's rows of ``arrays`` and its survivor decode column
+    ``dvec = D[:, k] * alive_k``; the output is already the stack of
+    decode-weighted copies, ready for the psum -- the separate ``D @ C~``
+    contraction never exists in the staged program.
     """
-    vals_t, src_t, wsl_all, t_tile, bt_pad, pad_cols = _block_sparse_operands(
+    arrays, lane, t_tile, bt_pad, pad_cols = _block_sparse_operands(
         plan, pack, bt)
 
-    def local_product_decode(k, A_, B_, dvec):
+    def local_product_decode(A_, B_, dvec, vals, src, wsl):
         out = ops.spmm_block_fused_decode(
-            vals_t[k], src_t[k], wsl_all[k], dvec, pad_cols(B_),
-            bt=bt_pad, t_tile=t_tile)
+            vals, src, wsl, dvec, pad_cols(B_), bt=bt_pad, t_tile=t_tile,
+            lane=lane)
         return out[:, :, :bt] if bt_pad != bt else out
 
-    return local_product_decode
+    return arrays, local_product_decode
 
 
 coded_backends.get_backend("dense_scan").local_product_factory = (
@@ -616,11 +600,10 @@ def resolve_pack(
     return pack
 
 
-def stage_coded_matmul(
-    A: jax.Array,
-    B: jax.Array,
+def build_coded_program(
     plan: CodedMatmulPlan,
     mesh: jax.sharding.Mesh,
+    bt: int,
     *,
     axis_name: str = "model",
     alive: np.ndarray | None = None,
@@ -628,31 +611,24 @@ def stage_coded_matmul(
     backend: str = "dense_scan",
     pack: WorkerTilePack | None = None,
     out_sharded: bool = False,
-) -> jax.Array:
-    """Stage the shard_map program for one coded matmul (the shared core).
+):
+    """The coded matmul as a pure function of its device operands.
 
-    ``plan`` must already be survivor-adjusted (its decode matrix re-derived
-    via ``with_survivors``) and ``alive`` is the matching worker-liveness
-    mask (None = all alive).  For backends with ``needs_pack``, ``pack``
-    must be pre-resolved (``resolve_pack``).  Both the legacy
-    ``coded_matmul`` shim and ``repro.coded.CodedOp`` funnel through here,
-    which is what makes old-vs-new bit-parity structural rather than
-    coincidental.
+    Returns ``(program, worker_arrays)``: ``program(A, B, *worker_arrays)``
+    computes C, and ``worker_arrays`` are host arrays with a leading
+    worker axis of size N -- the per-worker survivor decode columns, then
+    the backend's per-worker operands (task table or tile pack).  The
+    program takes them sharded over ``axis_name``, so each device holds
+    only its own worker's rows and nothing is baked into the compiled
+    program as a constant.  ``bt`` is the column-group width t / n.
     """
     entry = coded_backends.get_backend(backend)
     if entry.virtual:
         raise ValueError(
             f"backend {backend!r} is a dispatch pseudo-backend: resolve it "
             "to a concrete backend (CodedOp does this) before staging")
-    N, s, r, t, br, bt = _check_operands(A, B, plan, mesh, axis_name)
+    N = mesh.shape[axis_name]
     m, n = plan.m, plan.n
-
-    if alive is None:
-        alive_t = jnp.ones((N,), jnp.float32)
-    else:
-        alive_t = jnp.asarray(alive, dtype=jnp.float32)
-
-    D_t = jnp.asarray(plan.decode)         # (mn, N)
     if entry.needs_pack and pack is None:
         raise ValueError(
             f"backend {backend!r} needs a resolved WorkerTilePack "
@@ -662,45 +638,108 @@ def stage_coded_matmul(
             f"backend {backend!r} is registered but has no "
             "local_product_factory attached")
     fuse = entry.fused_decode and entry.fused_local_product_factory is not None
-    if fuse:
-        local_product_decode = entry.fused_local_product_factory(plan, pack, bt)
-    else:
-        local_product = entry.local_product_factory(plan, pack, bt)
+    factory = (entry.fused_local_product_factory if fuse
+               else entry.local_product_factory)
+    backend_arrays, local_product = factory(plan, pack, bt)
+
+    alive_f = (np.ones((N,), np.float32) if alive is None
+               else np.asarray(alive, dtype=np.float32))
+    # row k: worker k's survivor decode column D[:, k] * alive_k, (N, mn)
+    dvecs = np.ascontiguousarray(
+        (np.asarray(plan.decode, np.float32) * alive_f[None, :]).T)
 
     mn = m * n
     mn_pad = -(-mn // N) * N  # scatter splits the block dim N ways
 
-    def worker_fn(A_, B_):
-        k = jax.lax.axis_index(axis_name)
+    def worker_fn(A_, B_, dvec, *arrays):
+        dvec = dvec[0]
+        arrays = [a[0] for a in arrays]
         if fuse:
             # one-launch path: the decode combine happens in the kernel
             # epilogue, so the (mn, br, bt) contribution comes out of the
             # local product directly -- no D @ C~ contraction is staged
-            contrib = local_product_decode(k, A_, B_, D_t[:, k] * alive_t[k])
+            contrib = local_product(A_, B_, dvec, *arrays)
         else:
-            Ct = local_product(k, A_, B_)
+            Ct = local_product(A_, B_, *arrays)
             # decode contribution: blocks_c += D[c, k] * C~_k (zeroed if dead)
-            contrib = (D_t[:, k] * alive_t[k])[:, None, None] * Ct[None]
+            contrib = dvec[:, None, None] * Ct[None]
         if out_sharded:
             contrib = jnp.pad(contrib, ((0, mn_pad - mn), (0, 0), (0, 0)))
             # each device reduces only its 1/N shard of the block dim
             return compat.psum_scatter(contrib, axis_name,
                                        scatter_dimension=0, tiled=True)
         blocks = jax.lax.psum(contrib, axis_name)          # (mn, br, bt)
+        br = blocks.shape[1]
         C = blocks.reshape(m, n, br, bt).transpose(0, 2, 1, 3).reshape(m * br, n * bt)
         return C.astype(out_dtype)
 
+    worker_arrays = (dvecs, *backend_arrays)
     fn = compat.shard_map(
         worker_fn, mesh=mesh,
-        in_specs=(P(), P()),
+        in_specs=(P(), P()) + (P(axis_name),) * len(worker_arrays),
         out_specs=P(axis_name) if out_sharded else P(),
         check_vma=False,
     )
     if not out_sharded:
-        return fn(A, B)
-    blocks = fn(A, B)                                      # (mn_pad, br, bt)
-    C = blocks[:mn].reshape(m, n, br, bt).transpose(0, 2, 1, 3)
-    return C.reshape(m * br, n * bt).astype(out_dtype)
+        return fn, worker_arrays
+
+    def program(A, B, *arrays):
+        blocks = fn(A, B, *arrays)                         # (mn_pad, br, bt)
+        br = blocks.shape[1]
+        C = blocks[:mn].reshape(m, n, br, bt).transpose(0, 2, 1, 3)
+        return C.reshape(m * br, n * bt).astype(out_dtype)
+
+    return program, worker_arrays
+
+
+def _jitted_program(A, B, plan, mesh, axis_name, **kwargs):
+    *_, bt = _check_operands(A, B, plan, mesh, axis_name)
+    program, worker_arrays = build_coded_program(
+        plan, mesh, bt, axis_name=axis_name, **kwargs)
+    by_worker = jax.sharding.NamedSharding(mesh, P(axis_name))
+    return jax.jit(program), worker_arrays, by_worker
+
+
+def stage_coded_matmul(
+    A: jax.Array,
+    B: jax.Array,
+    plan: CodedMatmulPlan,
+    mesh: jax.sharding.Mesh,
+    *,
+    axis_name: str = "model",
+    **kwargs,
+) -> jax.Array:
+    """Compile and run the program of one coded matmul (the shared core).
+
+    Keyword arguments are those of ``build_coded_program``.  ``plan`` must
+    already be survivor-adjusted (its decode matrix re-derived via
+    ``with_survivors``) and ``alive`` is the matching worker-liveness mask
+    (None = all alive).  For backends with ``needs_pack``, ``pack`` must be
+    pre-resolved (``resolve_pack``).  Both the legacy ``coded_matmul`` shim
+    and ``repro.coded.CodedOp`` funnel through here, which is what makes
+    old-vs-new bit-parity structural rather than coincidental.  The
+    per-worker operands are placed one worker per device along
+    ``axis_name``.  Each call stages a fresh program, so each call compiles.
+    """
+    program, worker_arrays, by_worker = _jitted_program(
+        A, B, plan, mesh, axis_name, **kwargs)
+    return program(A, B, *(jax.device_put(a, by_worker)
+                           for a in worker_arrays))
+
+
+def lower_coded_matmul(A, B, plan: CodedMatmulPlan, mesh: jax.sharding.Mesh,
+                       *, axis_name: str = "model", **kwargs):
+    """``jax.stages.Lowered`` of the program ``stage_coded_matmul`` runs.
+
+    ``A`` and ``B`` may be arrays or ``jax.ShapeDtypeStruct``s (with their
+    shardings); the per-worker operands enter as shapes sharded by worker,
+    so ``.compile()`` works for a described topology with no chip attached.
+    """
+    program, worker_arrays, by_worker = _jitted_program(
+        A, B, plan, mesh, axis_name, **kwargs)
+    return program.lower(A, B, *(
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=by_worker)
+        for a in worker_arrays))
 
 
 def _coded_matmul(
@@ -784,5 +823,7 @@ def coded_matmul(
 
 
 def uncoded_matmul_reference(A, B):
-    """The plain product, for tests and overhead comparisons."""
-    return jnp.einsum("sr,st->rt", A, B, preferred_element_type=jnp.float32)
+    """The plain product, for tests and overhead comparisons (fp32
+    contraction on every backend)."""
+    return jnp.einsum("sr,st->rt", A, B, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)

@@ -21,10 +21,8 @@ Two entry points:
   group* of the original (s, t) B plus a per-slot f32 weight; the BlockSpec
   index_map DMAs tiles straight out of B and the kernel scales by the
   prefetched weight.  No stacked copy of B ever exists -- HBM traffic is
-  live tiles only.  Off TPU (no env override, no explicit ``interpret``)
-  it dispatches to an XLA gather/einsum path with identical semantics:
-  the Pallas interpreter is a correctness tool, orders of magnitude
-  slower than compiled XLA, and would bury the nnz-proportional win.
+  live tiles only.  ``_spmm_block_fused_jnp`` is the XLA gather/einsum
+  path with identical semantics (the CPU lane).
 * ``spmm_block_fused_decode`` -- the ONE-LAUNCH variant: the survivor
   decode column d = D[:, k] * alive_k enters as a third scalar-prefetched
   operand and the decode combine ``contrib[c] = d[c] * C~_k`` happens in
@@ -40,12 +38,16 @@ Grid: (CB, t_tiles, L) -- L innermost so each (rb, tt) output tile stays
 VMEM-resident across its accumulation; zero-padded slots multiply zero tiles
 (fused: weight 0.0) and add nothing.
 
-Platform lanes: the decode-fused kernel exists on every backend.  TPU runs
-this module's compiled Pallas kernel; GPU runs the Pallas-Triton variant
-(``repro.kernels.spmm_block_triton``, in-kernel gather loop instead of
-index-map prefetch); CPU runs the XLA gather path (or either kernel under
-the interpreter for parity tests).  ``resolve_lane`` is the single policy:
-REPRO_KERNEL_LANE=tpu|triton|xla overrides, then the default backend picks.
+Scalar prefetch lives in SMEM, where a multi-dimensional operand is padded
+to 128 words on its last axis.  The slot tables therefore go in flat (1-D)
+and are indexed arithmetically; ``check_slot_table_fits`` refuses, before
+anything compiles, a pack whose table would still overflow SMEM.
+
+Platform lanes: TPU runs this module's compiled Pallas kernels; CPU runs the
+XLA gather path (or the Pallas kernels under the interpreter for parity
+tests).  ``resolve_lane`` is the single policy: REPRO_KERNEL_LANE=tpu|xla
+overrides, then the default backend picks.  Any other backend is an error,
+never a silent fallback.
 """
 
 from __future__ import annotations
@@ -74,21 +76,31 @@ def _kernel(idx_ref, vals_ref, b_ref, o_ref):
     )
 
 
+def _backend_default(on_tpu, on_cpu, what: str):
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return on_tpu
+    if backend == "cpu":
+        return on_cpu
+    raise RuntimeError(
+        f"no {what} for JAX backend {backend!r}: the kernels run compiled on "
+        "TPU and interpreted (or on the XLA lane) on CPU only")
+
+
 def resolve_interpret(interpret: bool | None = None) -> bool:
     """The single interpret-mode policy for every Pallas kernel here.
 
     Explicit argument wins, then the REPRO_PALLAS_INTERPRET env override,
-    then backend auto-selection: compiled only on TPU.  The kernels target
-    the TPU MXU; everywhere else (CPU containers, tests) the Pallas
-    interpreter executes the same body faithfully, BlockSpec tiling
-    included.
+    then the backend: compiled on TPU, the Pallas interpreter on CPU (it
+    runs the same body faithfully, BlockSpec tiling included).  Any other
+    backend raises.
     """
     if interpret is not None:
         return interpret
     env = os.environ.get("REPRO_PALLAS_INTERPRET")
     if env is not None:
         return env != "0"
-    return jax.default_backend() != "tpu"
+    return _backend_default(False, True, "Pallas interpret policy")
 
 
 @functools.partial(jax.jit, static_argnames=("t_tile", "interpret"))
@@ -136,6 +148,35 @@ def spmm_block(vals, idx, B, *, t_tile: int = 128,
 
 # ------------------------------ fused gather --------------------------------
 
+#: SMEM bytes the scalar-prefetched slot tables of one launch may take.  A
+#: v5e TensorCore has 1 MiB of SMEM; a compile-only probe for a described
+#: v5e refused flat tables of 1057280 bytes as "Used 1.01M of 1.00M smem"
+#: (about 600 bytes besides the tables) and took 1032704, so 4 KiB is left
+#: for the kernel's other scalars.
+SMEM_PREFETCH_BYTES = (1 << 20) - 4096
+
+
+def _smem_words(n: int) -> int:
+    # counted in whole 128-word rows: an upper bound for a flat table
+    return -(-n // 128) * 128
+
+
+def check_slot_table_fits(CB: int, L: int, bs: int, mn: int = 0) -> None:
+    """Raise ValueError if a (CB, L)-slot pack cannot be scalar-prefetched.
+
+    The flat tables are src (2 int32 per slot), wslot (1 f32 per slot) and,
+    for the decode-fused kernel, the (mn,) decode column.  Fewer, larger
+    tiles (a larger ``block_size``) shrink CB * L.
+    """
+    need = 4 * (_smem_words(2 * CB * L) + _smem_words(CB * L)
+                + (_smem_words(mn) if mn else 0))
+    if need > SMEM_PREFETCH_BYTES:
+        raise ValueError(
+            f"block_size={bs} packs {CB} x {L} tile slots per worker, whose "
+            f"scalar-prefetched slot tables need {need} bytes of SMEM > the "
+            f"{SMEM_PREFETCH_BYTES}-byte limit; use a larger block_size")
+
+
 def _fused_kernel(src_ref, w_ref, vals_ref, b_ref, o_ref):
     cb = pl.program_id(0)
     l = pl.program_id(2)
@@ -144,13 +185,13 @@ def _fused_kernel(src_ref, w_ref, vals_ref, b_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    w = w_ref[cb, l].astype(jnp.float32)        # per-slot code weight
+    w = w_ref[cb * pl.num_programs(2) + l]      # per-slot code weight
     tile = vals_ref[0, 0].astype(jnp.float32)   # (bs, bs) tile of A
     b = b_ref[0].astype(jnp.float32)            # (bs, t_tile) rows of B
     # C[cb] += w * tile^T @ B[src_rb, :, src_jb-th column group]
     o_ref[...] += w * jax.lax.dot_general(
-        tile, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+        tile, b, (((0,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "t_tile"))
@@ -158,7 +199,7 @@ def _spmm_block_fused_jnp(vals, src, wslot, B, *, bt: int, t_tile: int = 0):
     """XLA gather/einsum path with the fused kernel's exact semantics.
 
     The only intermediates are (CB, L, bs, bt) -- proportional to packed
-    tile slots, never to max_degree * s.  Used off-TPU where compiled
+    tile slots, never to max_degree * s.  The CPU lane, where compiled
     Pallas is unavailable and the interpreter is too slow to be a backend.
     """
     del t_tile  # tiling is the compiler's business here
@@ -168,8 +209,34 @@ def _spmm_block_fused_jnp(vals, src, wslot, B, *, bt: int, t_tile: int = 0):
     bsel = B4[src[..., 0], :, src[..., 1], :]                # (CB, L, bs, bt)
     scaled = vals.astype(jnp.float32) * wslot[..., None, None].astype(jnp.float32)
     out = jnp.einsum("clio,clit->cot", scaled, bsel.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST,
                      preferred_element_type=jnp.float32)
     return out.reshape(CB * bs, bt)
+
+
+def _fused_specs(L, bs, bt, t_tile):
+    """The tile BlockSpecs both fused kernels share.  The B tile of slot
+    (cb, l) is row-block src[2*(cb*L+l)] and column tile tt of column group
+    src[2*(cb*L+l)+1] of B viewed as (s/bs, bs, t): the gather happens in
+    the DMA, no stacked B copy is ever built."""
+    tpg = bt // t_tile  # t_tiles per column group
+
+    def b_index(cb, tt, l, src_ref, *_):
+        slot = 2 * (cb * L + l)
+        return (src_ref[slot], 0, src_ref[slot + 1] * tpg + tt)
+
+    vals_spec = pl.BlockSpec((1, 1, bs, bs), lambda cb, tt, l, *_: (cb, l, 0, 0))
+    return vals_spec, pl.BlockSpec((1, bs, t_tile), b_index)
+
+
+def _check_fused_shapes(CB, L, bs, s, t, bt, t_tile, mn=0):
+    if bt % t_tile:
+        raise ValueError(f"bt={bt} not divisible by t_tile={t_tile}")
+    if t % bt:
+        raise ValueError(f"t={t} not divisible by column-group width bt={bt}")
+    if s % bs:
+        raise ValueError(f"s={s} not divisible by block size {bs}")
+    check_slot_table_fits(CB, L, bs, mn)
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "t_tile", "interpret"))
@@ -177,32 +244,12 @@ def _spmm_block_fused_pallas(vals, src, wslot, B, *, bt: int,
                              t_tile: int = 128, interpret: bool = False):
     CB, L, bs, _ = vals.shape
     s, t = B.shape
-    if bt % t_tile:
-        raise ValueError(f"bt={bt} not divisible by t_tile={t_tile}")
-    if t % bt:
-        raise ValueError(f"t={t} not divisible by column-group width bt={bt}")
-    if s % bs:
-        raise ValueError(f"s={s} not divisible by block size {bs}")
-
-    grid = (CB, bt // t_tile, L)
-    tpg = bt // t_tile  # t_tiles per column group
-
-    vals_spec = pl.BlockSpec(
-        (1, 1, bs, bs), lambda cb, tt, l, src_ref, w_ref: (cb, l, 0, 0)
-    )
-    # B viewed as (s/bs, bs, t): row-block src[cb,l,0], column tile tt of
-    # column group src[cb,l,1] -- the gather happens in the DMA, no stacked
-    # B copy is ever built.
-    b_spec = pl.BlockSpec(
-        (1, bs, t_tile),
-        lambda cb, tt, l, src_ref, w_ref: (
-            src_ref[cb, l, 0], 0, src_ref[cb, l, 1] * tpg + tt),
-    )
-    o_spec = pl.BlockSpec((bs, t_tile), lambda cb, tt, l, src_ref, w_ref: (cb, tt))
-
+    _check_fused_shapes(CB, L, bs, s, t, bt, t_tile)
+    vals_spec, b_spec = _fused_specs(L, bs, bt, t_tile)
+    o_spec = pl.BlockSpec((bs, t_tile), lambda cb, tt, l, *_: (cb, tt))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(CB, bt // t_tile, L),
         in_specs=[vals_spec, b_spec],
         out_specs=o_spec,
     )
@@ -211,52 +258,25 @@ def _spmm_block_fused_pallas(vals, src, wslot, B, *, bt: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((CB * bs, bt), jnp.float32),
         interpret=interpret,
-    )(src.astype(jnp.int32), wslot.astype(jnp.float32), vals,
-      B.reshape(s // bs, bs, t))
-
-
-def spmm_block_fused(vals, src, wslot, B, *, bt: int, t_tile: int = 128,
-                     interpret: bool | None = None):
-    """C_k = sum of w * tile^T @ B[row-block, column-group] over packed slots.
-
-    The fused-gather local product: A's packed tiles address the ORIGINAL
-    (s, t) operand B directly, so no (max_degree * s, bt) stacked copy is
-    materialized.
-
-    vals : (CB, L, bs, bs)  this worker's packed tiles of sparse A
-    src  : (CB, L, 2) int32 [source row-block of B (in s/bs), source column
-           group (in t/bt)]
-    wslot: (CB, L) f32      per-slot code weight (0.0 on padded slots)
-    B    : (s, t) with t divisible by bt, the column-group width.
-
-    Returns (CB * bs, bt) f32.  Dispatch: compiled Pallas on TPU; explicit
-    ``interpret`` or the REPRO_PALLAS_INTERPRET env force the Pallas path
-    (interpreted or compiled); otherwise off-TPU runs the XLA gather path
-    (same semantics, same nnz-proportional intermediates).
-    """
-    if (interpret is None and os.environ.get("REPRO_PALLAS_INTERPRET") is None
-            and jax.default_backend() != "tpu"):
-        return _spmm_block_fused_jnp(vals, src, wslot, B, bt=bt)
-    return _spmm_block_fused_pallas(vals, src, wslot, B, bt=bt, t_tile=t_tile,
-                                    interpret=resolve_interpret(interpret))
+    )(src.astype(jnp.int32).reshape(-1), wslot.astype(jnp.float32).reshape(-1),
+      vals, B.reshape(s // bs, bs, t))
 
 
 # ------------------------- fused gather + decode ----------------------------
 
-#: the three implementations of the decode-fused local product, keyed by the
-#: name ``resolve_lane`` returns (the table itself lives in kernels.ops to
-#: avoid a circular import with the triton module)
-KERNEL_LANES = ("tpu", "triton", "xla")
+#: the implementations of the decode-fused local product, keyed by the name
+#: ``resolve_lane`` returns
+KERNEL_LANES = ("tpu", "xla")
 
 
 def resolve_lane(lane: str | None = None) -> str:
-    """The single platform-dispatch policy for the decode-fused kernel.
+    """The single platform-dispatch policy for the fused kernels.
 
     Explicit argument wins, then the REPRO_KERNEL_LANE env override, then
-    the REPRO_PALLAS_INTERPRET escape hatch (which historically forced the
-    Pallas path and keeps doing so: it forces the TPU-kernel lane, run
-    under the interpreter off-TPU), then the default backend: compiled
-    Pallas-TPU on TPU, Pallas-Triton on GPU, the XLA gather path on CPU.
+    the REPRO_PALLAS_INTERPRET escape hatch (which forces the TPU-kernel
+    lane, run under the interpreter off-TPU), then the default backend:
+    compiled Pallas-TPU on TPU, the XLA gather path on CPU.  Any other
+    backend raises.
     """
     if lane is not None:
         if lane not in KERNEL_LANES:
@@ -271,12 +291,7 @@ def resolve_lane(lane: str | None = None) -> str:
     pallas_env = os.environ.get("REPRO_PALLAS_INTERPRET")
     if pallas_env is not None and pallas_env != "0":
         return "tpu"
-    backend = jax.default_backend()
-    if backend == "tpu":
-        return "tpu"
-    if backend == "gpu":
-        return "triton"
-    return "xla"
+    return _backend_default("tpu", "xla", "kernel lane")
 
 
 def _fused_decode_kernel(src_ref, w_ref, d_ref, vals_ref, b_ref, o_ref,
@@ -289,15 +304,15 @@ def _fused_decode_kernel(src_ref, w_ref, d_ref, vals_ref, b_ref, o_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = w_ref[cb, l].astype(jnp.float32)        # per-slot code weight
+    w = w_ref[cb * nl + l]                      # per-slot code weight
     tile = vals_ref[0, 0].astype(jnp.float32)   # (bs, bs) tile of A
     b = b_ref[0].astype(jnp.float32)            # (bs, t_tile) rows of B
     # C~[cb] += w * tile^T @ B[src_rb, :, src_jb-th column group] -- the
     # SAME accumulation (order and all) as the two-step kernel, into VMEM
     # scratch instead of the output ref
     acc_ref[...] += w * jax.lax.dot_general(
-        tile, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+        tile, b, (((0,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
     @pl.when(l == nl - 1)
     def _epilogue():
@@ -307,7 +322,7 @@ def _fused_decode_kernel(src_ref, w_ref, d_ref, vals_ref, b_ref, o_ref,
         # compile-time loop of scalar-from-SMEM broadcasts.
         acc = acc_ref[...]
         for c in range(o_ref.shape[0]):
-            o_ref[c] = d_ref[c].astype(jnp.float32) * acc
+            o_ref[c] = d_ref[c] * acc
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "t_tile", "interpret"))
@@ -317,31 +332,12 @@ def _spmm_block_fused_decode_pallas(vals, src, wslot, dvec, B, *, bt: int,
     CB, L, bs, _ = vals.shape
     s, t = B.shape
     (mn,) = dvec.shape
-    if bt % t_tile:
-        raise ValueError(f"bt={bt} not divisible by t_tile={t_tile}")
-    if t % bt:
-        raise ValueError(f"t={t} not divisible by column-group width bt={bt}")
-    if s % bs:
-        raise ValueError(f"s={s} not divisible by block size {bs}")
-
-    grid = (CB, bt // t_tile, L)
-    tpg = bt // t_tile  # t_tiles per column group
-
-    vals_spec = pl.BlockSpec(
-        (1, 1, bs, bs), lambda cb, tt, l, src_ref, w_ref, d_ref: (cb, l, 0, 0)
-    )
-    b_spec = pl.BlockSpec(
-        (1, bs, t_tile),
-        lambda cb, tt, l, src_ref, w_ref, d_ref: (
-            src_ref[cb, l, 0], 0, src_ref[cb, l, 1] * tpg + tt),
-    )
-    o_spec = pl.BlockSpec(
-        (mn, bs, t_tile), lambda cb, tt, l, src_ref, w_ref, d_ref: (0, cb, tt)
-    )
-
+    _check_fused_shapes(CB, L, bs, s, t, bt, t_tile, mn)
+    vals_spec, b_spec = _fused_specs(L, bs, bt, t_tile)
+    o_spec = pl.BlockSpec((mn, bs, t_tile), lambda cb, tt, l, *_: (0, cb, tt))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=grid,
+        grid=(CB, bt // t_tile, L),
         in_specs=[vals_spec, b_spec],
         out_specs=o_spec,
         scratch_shapes=[pltpu.VMEM((bs, t_tile), jnp.float32)],
@@ -351,7 +347,7 @@ def _spmm_block_fused_decode_pallas(vals, src, wslot, dvec, B, *, bt: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mn, CB * bs, bt), jnp.float32),
         interpret=interpret,
-    )(src.astype(jnp.int32), wslot.astype(jnp.float32),
+    )(src.astype(jnp.int32).reshape(-1), wslot.astype(jnp.float32).reshape(-1),
       dvec.astype(jnp.float32), vals, B.reshape(s // bs, bs, t))
 
 

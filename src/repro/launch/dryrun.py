@@ -1,12 +1,21 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")  # silence SPMD chatter
+"""Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-# --- everything below may import jax (device count is pinned above) ---------
+For each cell we jit the production step function with explicit in/out
+shardings on the production mesh, .lower().compile() it, and record
+memory_analysis / cost_analysis / the collective mix parsed from the
+compiled HLO.  Failures here are sharding bugs in the framework.
+
+Roofline probes: scan bodies are counted ONCE by HLO cost analysis, so for
+the roofline we also compile fully-unrolled shallow variants (1 and 2 layer
+groups; encoder depths likewise for enc-dec) and extrapolate exact per-group
+marginal costs.  Probes run on the single-pod mesh only (the roofline table
+is single-pod per the assignment).
+"""
 
 import argparse
 import dataclasses
 import json
+import os
 import pathlib
 import re
 import time
@@ -26,19 +35,6 @@ from repro.training.data import input_specs
 from repro.training.optimizer import AdamW
 from repro.training.train_step import make_train_step
 
-"""Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
-
-For each cell we jit the production step function with explicit in/out
-shardings on the production mesh, .lower().compile() it, and record
-memory_analysis / cost_analysis / the collective mix parsed from the
-compiled HLO.  Failures here are sharding bugs in the framework.
-
-Roofline probes: scan bodies are counted ONCE by HLO cost analysis, so for
-the roofline we also compile fully-unrolled shallow variants (1 and 2 layer
-groups; encoder depths likewise for enc-dec) and extrapolate exact per-group
-marginal costs.  Probes run on the single-pod mesh only (the roofline table
-is single-pod per the assignment).
-"""
 
 SHAPES = {
     "train_4k": dict(seq=4096, batch=256, kind="train"),
@@ -344,6 +340,10 @@ def sweep_cell(arch: str, shape: str, multi_pod: bool, outdir: pathlib.Path,
 
 
 def main():
+    # the production meshes are described on the host platform: pin its
+    # device count before the first backend call (never at import)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="all", help="arch id or 'all'")
     ap.add_argument("--shape", default="all", choices=["all"] + list(SHAPES))

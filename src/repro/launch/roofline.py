@@ -1,19 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")
-
-import argparse
-import dataclasses
-import json
-import pathlib
-
-import jax.numpy as jnp
-import numpy as np
-
-from repro.configs import ARCHS, get
-from repro.launch.dryrun import SHAPES, cell_supported, run_cell
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
-
 """Roofline analysis from compiled dry-run artifacts (single-pod mesh).
 
 HLO cost analysis counts scan/while bodies ONCE, so raw full-model numbers
@@ -34,40 +18,70 @@ Terms (per training/serving step, TPU v5e):
     collective_s = collective_bytes_per_device (x2 for all-reduce) / 50e9
 """
 
+import argparse
+import dataclasses
+import json
+import os
+import pathlib
+
+import jax.numpy as jnp
+import numpy as np
+
+from repro.configs import ARCHS, get
+from repro.launch.dryrun import SHAPES, cell_supported, run_cell
+from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+
+
 OUT_DIR = pathlib.Path(__file__).resolve().parents[3] / "experiments" / "roofline"
 DRYRUN_DIR = pathlib.Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
 
 # ------------------------- kernel-level roofline -----------------------------
 #
-# The model-arch analysis above prices whole training/serving steps against
-# the TPU v5e datasheet.  The coded-matmul KERNEL lanes (spmm_block_fused /
-# spmm_block_fused_decode, DESIGN.md section 12) need the same yardstick on
-# whatever host actually runs the bench -- CI is a CPU box -- so their peaks
-# are *calibrated in situ*: a dense f32 matmul for peak flops, a bandwidth-
-# bound elementwise pass for peak bytes/s.  Fraction-of-roofline then means
-# "of what THIS machine demonstrably sustains", not of a datasheet it never
-# matches, and the fused >= unfused acceptance comparison is machine-
-# independent.
+# The coded-matmul kernels (spmm_block_fused / spmm_block_fused_decode,
+# DESIGN.md section 12) are priced against the published peaks of the chip
+# they ran on, looked up by ``device_kind``.  A device that is not in the
+# table is an error, never a default.
 
-def machine_peaks(calibrate: bool | None = None, *, reps: int = 5) -> dict:
-    """{"peak_flops", "peak_bw", "source"} of the current default backend.
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops": PEAK_FLOPS_BF16,   # bf16 FLOP/s
+        "peak_int8_ops": 393e12,         # int8 OP/s
+        "peak_bw": HBM_BW,               # HBM bytes/s (16 GB)
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+                  "393 TOP/s int8, 819 GB/s HBM",
+    },
+}
 
-    calibrate=None measures on anything that is not a TPU (where the
-    datasheet constants above are the right ceiling).  Measurement is
-    deliberately favorable -- big square matmul, pure streaming pass -- so
-    the returned peaks are upper bounds and roofline fractions stay <= ~1.
+
+def machine_peaks(device_kind: str | None = None, *,
+                  calibrate_cpu: bool = False, reps: int = 5) -> dict:
+    """{"peak_flops", "peak_bw", "source", "device_kind", ...} of a chip.
+
+    ``device_kind`` defaults to that of ``jax.devices()[0]`` and must be a
+    key of ``DEVICE_PEAKS``.  ``calibrate_cpu=True`` instead measures the
+    CPU backend in place (a big square matmul, a pure streaming pass) for
+    CPU-only smoke runs; those numbers are labelled "calibrated-cpu" and
+    are never a device's peaks.
     """
     import time
 
     import jax
     import jax.numpy as jnp
 
-    if calibrate is None:
-        calibrate = jax.default_backend() != "tpu"
-    if not calibrate:
-        return {"peak_flops": PEAK_FLOPS_BF16, "peak_bw": HBM_BW,
-                "source": "datasheet-tpu-v5e"}
+    if calibrate_cpu:
+        if jax.default_backend() != "cpu":
+            raise ValueError(
+                "calibrate_cpu measures the CPU backend; the default backend "
+                f"is {jax.default_backend()!r} -- use its published peaks")
+    else:
+        kind = device_kind or jax.devices()[0].device_kind
+        if kind not in DEVICE_PEAKS:
+            raise ValueError(
+                f"no published peaks for device kind {kind!r}; known: "
+                f"{sorted(DEVICE_PEAKS)}")
+        return dict(DEVICE_PEAKS[kind], device_kind=kind)
 
     def best_time(fn, *args):
         fn(*args).block_until_ready()
@@ -88,7 +102,7 @@ def machine_peaks(calibrate: bool | None = None, *, reps: int = 5) -> dict:
     peak_bw = 2.0 * big.size * 4 / t_bw                    # read + write
 
     return {"peak_flops": float(peak_flops), "peak_bw": float(peak_bw),
-            "source": "calibrated"}
+            "source": "calibrated-cpu", "device_kind": "cpu"}
 
 
 def fused_kernel_cost(*, live_tiles: int, bs: int, bt: int, mn: int, br: int,
@@ -113,7 +127,7 @@ def fused_kernel_cost(*, live_tiles: int, bs: int, bt: int, mn: int, br: int,
 
 
 def roofline_fraction(cost: dict, measured_s: float, peaks: dict) -> float:
-    """Achieved fraction of this machine's roofline for the given cost.
+    """Achieved fraction of the roofline of ``peaks`` for the given cost.
 
     ideal = max(compute-bound, memory-bound) time; fraction = ideal /
     measured.  Compare paths at the SAME cost (the useful work) so the
@@ -306,6 +320,10 @@ def analyze_cell(arch: str, shape: str, *, chips: int = 256,
 
 
 def main():
+    # the production meshes are described on the host platform: pin its
+    # device count before the first backend call (never at import)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all", choices=["all"] + list(SHAPES))

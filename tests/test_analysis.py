@@ -157,14 +157,14 @@ def test_collective_axis_pass():
 
     from repro import compat
 
-    mesh = AbstractMesh((("model", 8),))
+    mesh = AbstractMesh((8,), ("model",))
     f = compat.shard_map(lambda x: jax.lax.psum(x, "model"), mesh=mesh,
                          in_specs=P("model"), out_specs=P())
     closed = jax.make_jaxpr(f)(jnp.ones((8, 4), jnp.float32))
-    assert collective_prims(closed.jaxpr) == ["psum2"]
+    assert collective_prims(closed.jaxpr) == ["psum_invariant"]
     assert collective_axis_offenders(closed.jaxpr, "model") == []
     assert collective_axis_offenders(closed.jaxpr, "data") == [
-        ("psum2", ("model",))]
+        ("psum_invariant", ("model",))]
 
 
 def test_float64_pass():
@@ -172,7 +172,7 @@ def test_float64_pass():
     from repro.analysis.jaxpr_check import float64_offenders
     import jax.numpy as jnp
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: jnp.sum(x * 2.0))(np.ones((4,), np.float64))
         assert float64_offenders(closed.jaxpr)

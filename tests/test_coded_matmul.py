@@ -10,7 +10,6 @@ from repro.coded import CodedMatmulConfig, from_plan
 from repro.core.coded_matmul import (
     BACKENDS,
     CodedMatmulPlan,
-    _largest_tile,
     make_plan,
     pack_worker_tiles,
     uncoded_matmul_reference,
@@ -146,18 +145,6 @@ def test_coded_matmul_rejects_unknown_backend():
     with pytest.raises(ValueError, match="backend"):
         CodedMatmulConfig(backend="nope")
     assert set(BACKENDS) == {"dense_scan", "block_sparse", "auto"}
-
-
-def test_largest_tile_picks_biggest_divisor_capped():
-    # the kernel tile width is the largest divisor of bt <= 128 -- never a
-    # degenerate whole-row tile when a proper divisor exists
-    assert _largest_tile(256) == 128
-    assert _largest_tile(128) == 128
-    assert _largest_tile(192) == 96   # old code would have fallen back to 192
-    assert _largest_tile(24) == 24
-    assert _largest_tile(130) == 65   # 65 divides 130 and is <= 128
-    assert _largest_tile(127) == 127  # prime <= 128: the row itself
-    assert _largest_tile(1) == 1
 
 
 def test_pack_worker_tiles_counts_live_tiles():

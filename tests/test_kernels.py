@@ -229,7 +229,7 @@ def test_spmm_block_fused_matches_packed_coded_product():
 # because both run the identical accumulation order; across lanes only
 # allclose holds (einsum vs sequential slot accumulation reassociate).
 
-LANES = ["xla", "tpu", "triton"]
+LANES = ["xla", "tpu"]
 
 
 def _fused_decode_case(seed=0, bs=8, CB=4, L=3, s=64, n=2, bt=128, mn=4):
@@ -266,16 +266,13 @@ def test_fused_decode_lanes_agree_allclose(lane):
 
 @pytest.mark.parametrize("bt,t_tile", [(24, 24), (40, 8)])
 def test_fused_decode_non_multiple_t_tile_shapes(bt, t_tile):
-    # bt not a multiple of 128: the tpu lane must still tile correctly
+    # bt not a multiple of 128: the tpu-lane kernel body (interpreted here)
+    # must still tile correctly
     vals, src, w, dvec, B = _fused_decode_case(seed=9, s=32, n=3, bt=bt)
     ref = ops.spmm_block_fused_decode(vals, src, w, dvec, B, bt=bt, lane="xla")
     got = ops.spmm_block_fused_decode(vals, src, w, dvec, B, bt=bt,
                                       t_tile=t_tile, lane="tpu")
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=1e-4, rtol=1e-4)
-    got_tr = ops.spmm_block_fused_decode(vals, src, w, dvec, B, bt=bt,
-                                         t_tile=t_tile, lane="triton")
-    np.testing.assert_allclose(np.asarray(got_tr), np.asarray(ref),
                                atol=1e-4, rtol=1e-4)
 
 
@@ -342,9 +339,9 @@ def test_resolve_lane_precedence(monkeypatch):
     assert resolve_lane() == "xla"                 # backend default on CPU
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     assert resolve_lane() == "tpu"                 # interpret opt-in
-    monkeypatch.setenv("REPRO_KERNEL_LANE", "triton")
-    assert resolve_lane() == "triton"              # env beats interpret
-    assert resolve_lane("xla") == "xla"            # explicit arg beats env
+    monkeypatch.setenv("REPRO_KERNEL_LANE", "xla")
+    assert resolve_lane() == "xla"                 # env beats interpret
+    assert resolve_lane("tpu") == "tpu"            # explicit arg beats env
     monkeypatch.setenv("REPRO_KERNEL_LANE", "cuda")
     with pytest.raises(ValueError, match="cuda"):
         resolve_lane()
@@ -352,28 +349,68 @@ def test_resolve_lane_precedence(monkeypatch):
         resolve_lane("metal")
 
 
+def test_lane_policy_raises_off_cpu_and_tpu(monkeypatch):
+    """On a backend that is neither CPU nor TPU the lane and interpret
+    policies raise instead of silently interpreting or taking the XLA lane."""
+    from repro.kernels import spmm_block
+
+    monkeypatch.delenv("REPRO_KERNEL_LANE", raising=False)
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(spmm_block.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        spmm_block.resolve_lane()
+    with pytest.raises(RuntimeError, match="gpu"):
+        spmm_block.resolve_interpret()
+    monkeypatch.setattr(spmm_block.jax, "default_backend", lambda: "tpu")
+    assert spmm_block.resolve_lane() == "tpu"
+    assert spmm_block.resolve_interpret() is False
+
+
+def test_oversized_slot_table_raises_before_compile():
+    """bs = 8 at r = s = 16384 packs 2048 column blocks; at 80 slots each
+    the flat slot tables need more SMEM than a v5e core has, so staging the
+    TPU kernel raises (naming block_size) before anything compiles."""
+    from repro.kernels.spmm_block import (
+        SMEM_PREFETCH_BYTES, _spmm_block_fused_decode_pallas)
+
+    CB, L, bs, s, bt = 2048, 80, 8, 16384, 128
+    args = (jax.ShapeDtypeStruct((CB, L, bs, bs), jnp.float32),
+            jax.ShapeDtypeStruct((CB, L, 2), jnp.int32),
+            jax.ShapeDtypeStruct((CB, L), jnp.float32),
+            jax.ShapeDtypeStruct((1,), jnp.float32),
+            jax.ShapeDtypeStruct((s, bt), jnp.float32))
+    with pytest.raises(ValueError, match=rf"block_size=8 .*{SMEM_PREFETCH_BYTES}"):
+        jax.eval_shape(
+            lambda *a: _spmm_block_fused_decode_pallas(*a, bt=bt), *args)
+    # the smoke width (bs = 128, 128 x 26 slots) fits
+    jax.eval_shape(lambda *a: _spmm_block_fused_decode_pallas(*a, bt=bt),
+                   jax.ShapeDtypeStruct((128, 26, 128, 128), jnp.float32),
+                   jax.ShapeDtypeStruct((128, 26, 2), jnp.int32),
+                   jax.ShapeDtypeStruct((128, 26), jnp.float32),
+                   jax.ShapeDtypeStruct((1,), jnp.float32),
+                   jax.ShapeDtypeStruct((16384, bt), jnp.float32))
+
+
 def test_plan_t_tiling_prime_bt_pads():
-    """Regression: prime bt used to degrade to t_tile=1 (one grid step per
-    column).  Now the t axis pads to a multiple of 8 and tiles properly."""
+    """The TPU lane tiles every column group in 128-wide tiles (Mosaic's
+    lane width); a bt that is not a multiple of 128 -- prime or not -- pads
+    up to one.  The XLA lane does not tile or pad."""
     from repro.core.coded_matmul import _plan_t_tiling
 
-    t_tile, bt_pad = _plan_t_tiling(13)            # small prime: one tile, fine
-    assert (t_tile, bt_pad) == (13, 13)
-    t_tile, bt_pad = _plan_t_tiling(128)           # no padding when aligned
-    assert (t_tile, bt_pad) == (128, 128)
-    t_tile, bt_pad = _plan_t_tiling(24)            # divisor exists: keep bt
-    assert bt_pad == 24 and 24 % t_tile == 0
-    t_tile, bt_pad = _plan_t_tiling(251)           # prime > cap: used to be 1
-    assert t_tile >= 8 and bt_pad % 8 == 0
-    assert bt_pad >= 251 and bt_pad % t_tile == 0
-    t_tile, bt_pad = _plan_t_tiling(2 * 127)       # 2*prime > cap: was 2
-    assert t_tile >= 8 and bt_pad >= 254 and bt_pad % t_tile == 0
+    assert _plan_t_tiling(128, "tpu") == (128, 128)   # aligned: no padding
+    assert _plan_t_tiling(384, "tpu") == (128, 384)
+    assert _plan_t_tiling(13, "tpu") == (128, 128)    # small prime
+    assert _plan_t_tiling(24, "tpu") == (128, 128)    # no 128-multiple divisor
+    assert _plan_t_tiling(251, "tpu") == (128, 256)   # prime > 128
+    assert _plan_t_tiling(2 * 127, "tpu") == (128, 256)
+    for bt in (13, 24, 251):
+        assert _plan_t_tiling(bt, "xla") == (bt, bt)
 
 
-def test_fused_decode_prime_bt_end_to_end():
+def test_fused_decode_prime_bt_end_to_end(monkeypatch):
     """The padded-t staging path: a per-worker coded product with prime
-    bt=251 (> the 128 tile cap, so the t axis genuinely pads to 256) must
-    match the dense reference after the pad+slice."""
+    bt=251 on the TPU lane (interpreted here; the t axis genuinely pads to
+    256) must match the dense reference after the pad+slice."""
     from repro.core.coded_matmul import (
         _make_block_sparse_fused_decode, make_plan, pack_worker_tiles)
 
@@ -388,10 +425,12 @@ def test_fused_decode_prime_bt_end_to_end():
     B = jnp.asarray(rng.standard_normal((s, t)), jnp.float32)
     ell = dense_to_block_ell(A, block_size=bs)
     pack = pack_worker_tiles(ell, plan)
-    fused = _make_block_sparse_fused_decode(plan, pack, bt)
+    monkeypatch.setenv("REPRO_KERNEL_LANE", "tpu")
+    arrays, fused = _make_block_sparse_fused_decode(plan, pack, bt)
     dvec = jnp.asarray(rng.standard_normal(4).astype(np.float32))
     for k in [0, 3]:
-        got = np.asarray(fused(jnp.asarray(k), jnp.asarray(A), B, dvec))
+        got = np.asarray(fused(jnp.asarray(A), B, dvec,
+                               *(jnp.asarray(a[k]) for a in arrays)))
         assert got.shape == (4, r // 2, bt)
         Ct = np.zeros((r // 2, bt), np.float32)
         for l in range(plan.max_degree):

@@ -189,3 +189,53 @@ def test_report_tables_render(tmp_path):
     r = roofline_table()
     assert "dominant" in r or "arch" in r
     perf_table()  # renders without error even if variants are sparse
+
+
+def test_machine_peaks_by_device_kind():
+    """Peaks come from the published table by device kind; an unknown kind
+    (the CPU backend here included) raises instead of defaulting, and CPU
+    calibration is opt-in and labelled as such."""
+    from repro.launch.roofline import DEVICE_PEAKS, machine_peaks
+
+    v5e = machine_peaks("TPU v5 lite")
+    assert (v5e["peak_flops"], v5e["peak_bw"]) == (197e12, 819e9)
+    assert v5e["peak_int8_ops"] == 393e12 and "TPU v5e" in v5e["source"]
+    assert set(DEVICE_PEAKS) == {"TPU v5 lite"}
+    with pytest.raises(ValueError, match="no published peaks"):
+        machine_peaks("TPU v9 imaginary")
+    with pytest.raises(ValueError, match="no published peaks"):
+        machine_peaks()                        # this CPU has none
+    cpu = machine_peaks(calibrate_cpu=True, reps=1)
+    assert cpu["source"] == "calibrated-cpu" and cpu["peak_flops"] > 0
+
+
+def test_roofline_import_sets_no_xla_flags():
+    """Importing the kernel-roofline helpers (and the dry-run module they
+    pull in) must not touch the environment; only their main()s set the
+    host device count."""
+    code = ("import os; os.environ.pop('XLA_FLAGS', None); "
+            "import repro.launch.roofline; "
+            "print(os.environ.get('XLA_FLAGS'))")
+    env = dict(os.environ, PYTHONPATH=str(pathlib.Path(__file__).parents[1] / "src"))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip() == "None"
+
+
+def test_compile_cache_dir_env_wins_else_fixed_checkout_path(monkeypatch):
+    import jax
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == str(pathlib.Path(__file__).parents[1] / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
