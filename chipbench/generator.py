@@ -1,0 +1,148 @@
+"""The one traffic generator: operands and schedules from the files of a cell.
+
+A's density belongs to the configuration (``configs/<name>.json``): ``nnz_a``
+nonzeros drawn uniformly over the (s, r) matrix, as the paper draws them.
+The device path holds A in ``block_size`` x ``block_size`` tiles, so a tile
+is live where at least one nonzero falls into it (``live_tile_fraction``),
+and a live tile is dense.  Every column block of A holds the same number of
+live tiles, at row positions drawn uniformly, so every seed stages the same
+shapes and the same work.
+
+A traffic file (``traffic/<name>.json``) holds the loop's parameters only;
+any other key is refused:
+
+* ``b_operands`` -- how many dense B operands the closed loop cycles through;
+* ``warmup_products`` -- how many products set-up runs before the window;
+* ``membership`` (optional) -- ``healthy_products`` products with every
+  worker alive, then ``degraded_products`` with ``dead_workers`` workers dead,
+  and so on; each dead set is drawn from the seed among those whose loss
+  keeps the code decodable.
+
+This module uses NumPy only; the harness turns what it returns into device
+arrays.  Each use draws from its own stream of the seed, so adding a draw to
+one never moves another.
+"""
+
+from __future__ import annotations
+
+import itertools
+import json
+import math
+import pathlib
+
+import numpy as np
+
+STREAMS = ("tiles", "b", "membership", "check")
+
+
+TRAFFIC_KEYS = {"name", "why", "b_operands", "warmup_products", "membership"}
+MEMBERSHIP_KEYS = {"healthy_products", "degraded_products", "dead_workers"}
+
+
+def load(path: pathlib.Path) -> dict:
+    with open(path) as f:
+        traffic = json.load(f)
+    unknown = set(traffic) - TRAFFIC_KEYS
+    if unknown:
+        raise ValueError(f"{path}: unknown keys {sorted(unknown)}")
+    if int(traffic.get("b_operands", 1)) < 1:
+        raise ValueError(f"{path}: b_operands must be >= 1")
+    member = traffic.get("membership")
+    if member is not None and set(member) != MEMBERSHIP_KEYS:
+        raise ValueError(f"{path}: membership needs exactly the keys "
+                         f"{sorted(MEMBERSHIP_KEYS)}")
+    return traffic
+
+
+def live_tile_fraction(nnz: int, rows: int, cols: int, block_size: int) -> float:
+    """The share of an (rows, cols) matrix's ``block_size``-square tiles that
+    hold at least one of ``nnz`` uniformly drawn nonzeros: a tile receives a
+    Poisson number of them with mean nnz * block_size**2 / (rows * cols)."""
+    return -math.expm1(-nnz * block_size ** 2 / (rows * cols))
+
+
+def streams(seed: int) -> dict[str, np.random.Generator]:
+    """Independent generators for each use of the seed (any non-negative int)."""
+    children = np.random.SeedSequence(int(seed)).spawn(len(STREAMS))
+    return {name: np.random.default_rng(c) for name, c in zip(STREAMS, children)}
+
+
+def jax_seed(rng: np.random.Generator) -> int:
+    """A seed that ``jax.random.key`` takes with 32-bit integers."""
+    return int(rng.integers(0, 2 ** 31 - 1))
+
+
+def tile_pattern(rng: np.random.Generator, *, row_blocks: int,
+                 col_blocks: int, live_fraction: float) -> np.ndarray:
+    """(col_blocks, k) sorted row-block indices of A's live tiles."""
+    k = max(1, round(live_fraction * row_blocks))
+    order = np.argsort(rng.random((col_blocks, row_blocks)), axis=1)
+    return np.sort(order[:, :k], axis=1).astype(np.int32)
+
+
+def tile_values(rng: np.random.Generator, shape) -> np.ndarray:
+    """Standard-normal f32 tile values."""
+    return rng.standard_normal(shape, dtype=np.float32)
+
+
+def decodable_masks(coefficients: np.ndarray, dead_workers: int) -> list[np.ndarray]:
+    """Every liveness mask with ``dead_workers`` dead whose surviving rows of
+    the (N, mn) coefficient matrix still have full column rank."""
+    N, d = coefficients.shape
+    masks = []
+    for dead in itertools.combinations(range(N), dead_workers):
+        alive = np.ones(N, bool)
+        alive[list(dead)] = False
+        if np.linalg.matrix_rank(coefficients * alive[:, None]) >= d:
+            masks.append(alive)
+    return masks
+
+
+class Schedule:
+    """Which B operand and which liveness mask product ``i`` of the loop uses.
+
+    ``mask(i)`` is None while every worker is alive.  The sequence is a
+    function of the traffic file, the code and the seed alone.
+    """
+
+    def __init__(self, traffic: dict, rng: np.random.Generator,
+                 coefficients: np.ndarray | None = None):
+        self.b_operands = int(traffic.get("b_operands", 1))
+        member = traffic.get("membership")
+        self.period = 1
+        self.healthy = 1
+        self.masks: list[np.ndarray] = []
+        self._draws: list[int] = []
+        self._rng = rng
+        if member:
+            if coefficients is None:
+                raise ValueError("a membership schedule needs the code's "
+                                 "coefficient matrix")
+            self.healthy = int(member["healthy_products"])
+            self.period = self.healthy + int(member["degraded_products"])
+            self.masks = decodable_masks(coefficients,
+                                         int(member["dead_workers"]))
+            if not self.masks:
+                raise ValueError(
+                    f"no set of {member['dead_workers']} dead workers keeps "
+                    "the code decodable")
+
+    def b_index(self, i: int) -> int:
+        return i % self.b_operands
+
+    def mask(self, i: int) -> np.ndarray | None:
+        """The liveness mask of product ``i``, None when all are alive."""
+        if not self.masks or i % self.period < self.healthy:
+            return None
+        cycle = i // self.period
+        while len(self._draws) <= cycle:
+            self._draws.append(int(self._rng.integers(len(self.masks))))
+        return self.masks[self._draws[cycle]]
+
+    def warmup(self, products: int) -> list[tuple[int, np.ndarray | None]]:
+        """The (B operand, mask) pairs of set-up's products: every operand and
+        every mask the loop can use first, then again in turn up to
+        ``products``."""
+        pairs = [(b, None) for b in range(self.b_operands)]
+        pairs += [(0, m) for m in self.masks]
+        return [pairs[j % len(pairs)] for j in range(max(products, len(pairs)))]
