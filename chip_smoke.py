@@ -78,29 +78,6 @@ def require_tpu_lane():
     return jax
 
 
-class CompileCounter:
-    """Counts XLA compile requests and persistent-cache hits."""
-
-    def __init__(self):
-        from jax import monitoring
-
-        self.compiles = 0
-        self.cache_hits = 0
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def snapshot(self):
-        return self.compiles, self.cache_hits
-
-
 def make_operands(seed: int):
     """Host A (block-sparse), B (dense), and the checked column subset."""
     rng = np.random.default_rng(seed)
@@ -170,14 +147,19 @@ def within(name, got, want, tol, what):
              f"{what} result beyond their tolerance")
 
 
-def timed(fn, counter):
-    c0, h0 = counter.snapshot()
+def timed(fn):
+    """``fn()`` ready, its wall time, and the backend compiles and cache
+    read-backs the program counted in its products (``repro.obs``)."""
+    from repro import obs
+
+    before = obs.snapshot()
     t0 = time.perf_counter()
     out = fn()
     out.block_until_ready()
     dt = time.perf_counter() - t0
-    c1, h1 = counter.snapshot()
-    return out, dt, c1 - c0, h1 - h0
+    after = obs.snapshot()
+    return out, dt, *(after.get(k, 0) - before.get(k, 0)
+                      for k in ("compiles", "readbacks"))
 
 
 def peak_bytes(dev) -> str:
@@ -185,7 +167,7 @@ def peak_bytes(dev) -> str:
     return str(stats.get("peak_bytes_in_use", "not reported"))
 
 
-def one_chip(jax, args, counter):
+def one_chip(jax, args):
     from repro import compat
     from repro.coded import CodedMatmulConfig, plan
     from repro.sparse import dense_to_block_ell
@@ -219,10 +201,10 @@ def one_chip(jax, args, counter):
                      "-- the Pallas kernel is not in it")
             log(f"{name}: tpu_custom_call present in the compiled program")
             del compiled
-        C, dt1, n1, h1 = timed(lambda: op(A, B, **kw), counter)
-        log(f"{name}: call1_s={dt1:.6f} compiles={n1} cache_hits={h1}")
-        C, dt2, n2, h2 = timed(lambda: op(A, B, **kw), counter)
-        log(f"{name}: call2_s={dt2:.6f} compiles={n2} cache_hits={h2} "
+        C, dt1, n1, h1 = timed(lambda: op(A, B, **kw))
+        log(f"{name}: call1_s={dt1:.6f} compiles={n1} readbacks={h1}")
+        C, dt2, n2, h2 = timed(lambda: op(A, B, **kw))
+        log(f"{name}: call2_s={dt2:.6f} compiles={n2} readbacks={h2} "
             f"recompiled_on_second_call={'yes' if n2 else 'no'}")
         tol = entry_tolerance(op.plan_, np.ones(1), absab, U_TILE[dtype], S)
         check(name, C, cols, ref, tol)
@@ -230,7 +212,7 @@ def one_chip(jax, args, counter):
         del C
 
 
-def four_chips(jax, args, counter):
+def four_chips(jax, args):
     import jax.numpy as jnp
 
     from repro import compat
@@ -254,7 +236,7 @@ def four_chips(jax, args, counter):
         f"{time.perf_counter() - t0:.3f}s")
     # the single-device uncoded product, at the same f32 contraction
     U, dt, _, _ = timed(lambda: uncoded_matmul_reference(
-        jax.device_put(A, devs[0]), jax.device_put(B, devs[0])), counter)
+        jax.device_put(A, devs[0]), jax.device_put(B, devs[0])))
     U_cols = np.asarray(U[:, cols], np.float64)
     del U
     unc_tol = (S * U32 / (1 - S * U32)) * absab
@@ -293,11 +275,11 @@ def four_chips(jax, args, counter):
                               for d, ix in sh.devices_indices_map((N,)).items())
                 log(f"{name}: worker operand {idx} (device id, row): {rows}")
             del compiled, text
-            C, dt, nc, hits = timed(lambda: op(A, B, a_sparse=ell), counter)
+            C, dt, nc, hits = timed(lambda: op(A, B, a_sparse=ell))
             shards = sorted((s.device.id, tuple((i.start, i.stop)
                                                 for i in s.index))
                             for s in C.addressable_shards)
-            log(f"{name}: wall_s={dt:.6f} compiles={nc} cache_hits={hits} "
+            log(f"{name}: wall_s={dt:.6f} compiles={nc} readbacks={hits} "
                 f"output shards (device id, index) {shards}")
             tol = entry_tolerance(op.plan_, mask, absab, U_TILE["float32"], S)
             check(name, C, cols, ref, tol)
@@ -324,11 +306,10 @@ def main(argv=None) -> int:
         f"libtpu={importlib.metadata.version('libtpu')} "
         f"device_kind={dev.device_kind} devices={len(jax.devices())} "
         f"compile_cache={cache}")
-    counter = CompileCounter()
     if args.four_chips:
-        four_chips(jax, args, counter)
+        four_chips(jax, args)
     else:
-        one_chip(jax, args, counter)
+        one_chip(jax, args)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}))

@@ -30,6 +30,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
 from repro.coded.config import CodedMatmulConfig
 from repro.coded import registry
 from repro.core import coded_backends
@@ -85,6 +86,7 @@ class CodedOp:
                 f"{self.plan_.num_workers}")
         return dataclasses.replace(self, mesh=mesh)
 
+    @obs.span(obs.REBIND)
     def with_survivors(self, survivors) -> "CodedOp":
         """Rebind to a liveness mask (replaces any previous mask).
 
@@ -97,6 +99,7 @@ class CodedOp:
         either way: they depend only on the base task table.  Passing None
         (or an all-complete mask) restores the original plan.
         """
+        obs.count("rebinds")
         if survivors is None:
             return dataclasses.replace(self, plan_=self.base_plan,
                                        survivors=None, chunk_progress=None)
@@ -161,6 +164,7 @@ class CodedOp:
                   else "dense_scan")
         return chosen, frac, a_sparse
 
+    @obs.span(obs.PREPARE)
     def _staging_kwargs(self, A, B, a_sparse, pack) -> dict:
         """Resolve backend and tile pack for ``A``, ``B``: the keyword
         arguments of ``stage_coded_matmul`` / ``lower_coded_matmul``."""
@@ -205,10 +209,14 @@ class CodedOp:
         ``config.auto_density_threshold`` and dispatches to block_sparse
         (sparse enough) or dense_scan; the density inputs are consumed by
         that decision and simply dropped when dense_scan wins.
+
+        Each call is one product of ``repro.obs``: a ``repro.product`` span
+        over the call, its staging spans beneath it.
         """
-        return stage_coded_matmul(
-            A, B, self.plan_, self.mesh,
-            **self._staging_kwargs(A, B, a_sparse, pack))
+        with obs.product():
+            return stage_coded_matmul(
+                A, B, self.plan_, self.mesh,
+                **self._staging_kwargs(A, B, a_sparse, pack))
 
     def lower(self, A, B, *, a_sparse: BlockELL | None = None,
               pack: WorkerTilePack | None = None):

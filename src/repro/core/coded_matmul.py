@@ -65,7 +65,7 @@ import numpy as np
 import scipy.sparse as sp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro import compat, obs
 from repro.core import coded_backends
 from repro.core.decoder import DecodingError, decode_matrix
 from repro.core.encoder import (
@@ -600,6 +600,7 @@ def resolve_pack(
     return pack
 
 
+@obs.span(obs.PREPARE)
 def build_coded_program(
     plan: CodedMatmulPlan,
     mesh: jax.sharding.Mesh,
@@ -723,8 +724,11 @@ def stage_coded_matmul(
     """
     program, worker_arrays, by_worker = _jitted_program(
         A, B, plan, mesh, axis_name, **kwargs)
-    return program(A, B, *(jax.device_put(a, by_worker)
-                           for a in worker_arrays))
+    with obs.span(obs.UPLOAD):
+        arrays = [jax.device_put(a, by_worker) for a in worker_arrays]
+    obs.count("upload_bytes", sum(a.nbytes for a in worker_arrays))
+    with obs.span(obs.JIT):
+        return program(A, B, *arrays)
 
 
 def lower_coded_matmul(A, B, plan: CodedMatmulPlan, mesh: jax.sharding.Mesh,

@@ -347,6 +347,7 @@ def _spmm_block_fused_decode_pallas(vals, src, wslot, dvec, B, *, bt: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mn, CB * bs, bt), jnp.float32),
         interpret=interpret,
+        name="spmm_block_fused_decode",
     )(src.astype(jnp.int32).reshape(-1), wslot.astype(jnp.float32).reshape(-1),
       dvec.astype(jnp.float32), vals, B.reshape(s // bs, bs, t))
 
