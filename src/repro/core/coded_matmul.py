@@ -73,7 +73,7 @@ from repro.core.encoder import (
     chunk_slices,
     generate_coefficient_matrix,
 )
-from repro.kernels import ops
+from repro.kernels import ops, spmm_block
 from repro.sparse.blocksparse import BlockELL, dense_to_block_ell
 
 # Snapshot of the registered backend names at import time; prefer
@@ -414,18 +414,47 @@ def pack_worker_tiles(a_sparse: BlockELL, plan: CodedMatmulPlan,
 
 # ------------------------------- entry point --------------------------------
 
-def _plan_t_tiling(bt: int, lane: str) -> tuple[int, int]:
+def _plan_t_tiling(bt: int, lane: str, *, bs: int, mn: int,
+                   itemsize: int) -> tuple[int, int]:
     """(t_tile, bt_pad) for the kernel grid over a bt-wide column group.
 
-    The TPU lane tiles the column group in 128-wide tiles, the lane width
-    Mosaic requires of a block's last dimension; a ``bt`` that is not a
-    multiple of 128 is zero-padded up to one, and the caller slices the pad
-    columns back off.  Zero columns contribute nothing, so the kept columns
-    are bitwise unchanged.  The XLA lane does not tile.
+    On the TPU lane a ``bt`` that is not a multiple of 128 (the lane width
+    Mosaic requires of a block's last dimension) is zero-padded up to one,
+    and the caller slices the pad columns back off.  Zero columns contribute
+    nothing, so the kept columns are bitwise unchanged.  ``t_tile`` is the
+    widest multiple of 128 that divides ``bt_pad`` and whose VMEM footprint
+    -- ``spmm_block.fused_vmem_bytes`` of the tile edge ``bs``, the ``mn``
+    decode rows of the output block and the pack's ``itemsize`` -- fits
+    ``spmm_block.VMEM_TILE_BYTES``; 128 where no wider one does.  The grid
+    is then (CB, bt_pad / t_tile, L).  The XLA lane does not tile.
     """
     if lane == "xla":
         return bt, bt
-    return 128, -(-bt // 128) * 128
+    bt_pad = -(-bt // 128) * 128
+    fits = [t for t in range(128, bt_pad + 1, 128)
+            if bt_pad % t == 0 and spmm_block.fused_vmem_bytes(
+                bs, mn, t, itemsize) <= spmm_block.VMEM_TILE_BYTES]
+    return max(fits, default=128), bt_pad
+
+
+def _kernel_grid(plan: CodedMatmulPlan, pack: WorkerTilePack, bt: int):
+    """(lane, t_tile, bt_pad) of one worker's block-sparse kernel launch."""
+    lane = ops.resolve_lane()
+    t_tile, bt_pad = _plan_t_tiling(
+        bt, lane, bs=pack.block_size, mn=plan.m * plan.n,
+        itemsize=pack.vals.dtype.itemsize)
+    return lane, t_tile, bt_pad
+
+
+def _kernel_grid_steps(plan: CodedMatmulPlan, pack: WorkerTilePack,
+                       bt: int) -> int:
+    """Grid steps of one worker's kernel launch on the TPU lane,
+    CB * (bt_pad / t_tile) * L; 0 on the XLA lane, which has no grid."""
+    lane, t_tile, bt_pad = _kernel_grid(plan, pack, bt)
+    if lane != "tpu":
+        return 0
+    _, CB, L = pack.wslot.shape
+    return CB * (bt_pad // t_tile) * L
 
 
 def _make_dense_scan_local_product(plan: CodedMatmulPlan, pack, bt: int):
@@ -449,8 +478,7 @@ def _block_sparse_operands(plan: CodedMatmulPlan, pack: WorkerTilePack,
         raise ValueError(
             "WorkerTilePack has no slot_of map (built by a pre-chunking "
             "packer?); rebuild it with pack_worker_tiles")
-    lane = ops.resolve_lane()
-    t_tile, bt_pad = _plan_t_tiling(bt, lane)
+    lane, t_tile, bt_pad = _kernel_grid(plan, pack, bt)
     # The pack carries the BASE task table's weights; the staged plan may
     # have zeroed some (chunk-prefix masking).  Re-read each live tile's
     # weight from the *current* plan through slot_of so one pack serves
@@ -727,6 +755,10 @@ def stage_coded_matmul(
     with obs.span(obs.UPLOAD):
         arrays = [jax.device_put(a, by_worker) for a in worker_arrays]
     obs.count("upload_bytes", sum(a.nbytes for a in worker_arrays))
+    if coded_backends.get_backend(kwargs.get("backend", "dense_scan")).needs_pack:
+        steps = _kernel_grid_steps(plan, kwargs["pack"], B.shape[1] // plan.n)
+        if steps:
+            obs.count("kernel_grid_steps", steps)
     with obs.span(obs.JIT):
         return program(A, B, *arrays)
 

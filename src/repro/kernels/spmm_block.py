@@ -36,7 +36,11 @@ Two entry points:
 
 Grid: (CB, t_tiles, L) -- L innermost so each (rb, tt) output tile stays
 VMEM-resident across its accumulation; zero-padded slots multiply zero tiles
-(fused: weight 0.0) and add nothing.
+(fused: weight 0.0) and add nothing.  For the fused kernels t_tiles is
+bt / t_tile, and the staging code picks the widest t_tile whose VMEM
+footprint (``fused_vmem_bytes``) fits ``VMEM_TILE_BYTES``: each grid step
+then contracts one A tile with a (bs, t_tile) slab of B, and A's tiles are
+fetched once per column tile rather than once per 128 columns.
 
 Scalar prefetch lives in SMEM, where a multi-dimensional operand is padded
 to 128 words on its last axis.  The slot tables therefore go in flat (1-D)
@@ -154,6 +158,28 @@ def spmm_block(vals, idx, B, *, t_tile: int = 128,
 #: (about 600 bytes besides the tables) and took 1032704, so 4 KiB is left
 #: for the kernel's other scalars.
 SMEM_PREFETCH_BYTES = (1 << 20) - 4096
+
+
+#: VMEM bytes the blocks of one fused-kernel launch may take, as
+#: ``fused_vmem_bytes`` counts them.  It stays under the 16 MiB default
+#: scoped-VMEM limit of a v5e TensorCore, so no ``vmem_limit_bytes`` is
+#: needed.  On one v5e, at CB = 128, L = 45, bs = 128 and a 16384-wide f32
+#: column group, the kernel took 392.0, 161.3, 125.4, 110.5 and 103.6 ms at
+#: t_tile 128, 512, 1024, 2048 and 4096, with bit-identical results; 8192
+#: needs more than the default limit.  This budget admits 4096 for one
+#: decode row of an f32 or bf16 pack (14.1 MiB counted), and 2048 for two.
+VMEM_TILE_BYTES = 15 << 20
+
+
+def fused_vmem_bytes(bs: int, mn: int, t_tile: int, itemsize: int) -> int:
+    """VMEM of a fused-decode launch at column tile ``t_tile``: the
+    double-buffered A tile (``bs * bs * itemsize`` each), B tile and
+    (mn, bs, t_tile) output block, the f32 accumulator, and about two
+    result-sized temporaries (the dot's result and its weighted copy).
+    Mosaic's own scoped allocation for the kernel counts the blocks and the
+    accumulator alone, so the temporaries are a margin."""
+    result = bs * t_tile * 4
+    return 2 * bs * bs * itemsize + 2 * result + 2 * mn * result + 3 * result
 
 
 def _smem_words(n: int) -> int:

@@ -23,11 +23,12 @@ spans, each in the module where its work happens:
 
 Counters are process-wide integers, incremented at the same boundaries:
 ``products``, ``upload_bytes`` (the ``nbytes`` of the worker arrays put on
-the devices), ``compiles`` (backend compiles inside a product, read-backs
-included), ``readbacks`` (executables read back from the persistent cache
-inside a product) and ``rebinds``.  A product's span also carries the
-counts that moved while it was open, so a window of products can be read
-without a counter snapshot at its start.
+the devices), ``kernel_grid_steps`` (the grid steps of one worker's
+block-sparse kernel launch, on the TPU lane only), ``compiles`` (backend
+compiles inside a product, read-backs included), ``readbacks`` (executables
+read back from the persistent cache inside a product) and ``rebinds``.  A
+product's span also carries the counts that moved while it was open, so a
+window of products can be read without a counter snapshot at its start.
 
 Every span enters ``jax.profiler.TraceAnnotation`` of its name (the root a
 ``StepTraceAnnotation`` numbered by its product id), so a profiler trace

@@ -391,20 +391,66 @@ def test_oversized_slot_table_raises_before_compile():
                    jax.ShapeDtypeStruct((16384, bt), jnp.float32))
 
 
-def test_plan_t_tiling_prime_bt_pads():
-    """The TPU lane tiles every column group in 128-wide tiles (Mosaic's
-    lane width); a bt that is not a multiple of 128 -- prime or not -- pads
-    up to one.  The XLA lane does not tile or pad."""
+@pytest.mark.parametrize("bt,bs,mn,itemsize,want", [
+    (128, 128, 1, 4, (128, 128)),      # aligned: no padding
+    (384, 128, 1, 4, (384, 384)),      # the whole group fits in one tile
+    (13, 128, 1, 4, (128, 128)),       # small prime
+    (24, 128, 1, 4, (128, 128)),       # no 128-multiple divisor
+    (251, 128, 1, 4, (256, 256)),      # prime > 128: 256 divides 256
+    (2 * 127, 128, 1, 4, (256, 256)),
+    (16384, 128, 1, 4, (4096, 16384)),  # the cell: one decode row, f32
+    (16384, 128, 2, 4, (2048, 16384)),  # two decode rows: a larger output
+    (16384, 128, 1, 2, (4096, 16384)),  # bf16 pack
+    (16384, 128, 2, 2, (2048, 16384)),
+], ids=["128", "384", "13", "24", "251", "254", "16384-mn1-f32",
+        "16384-mn2-f32", "16384-mn1-bf16", "16384-mn2-bf16"])
+def test_plan_t_tiling_prime_bt_pads(bt, bs, mn, itemsize, want):
+    """The TPU lane pads a bt that is not a multiple of 128 (Mosaic's lane
+    width) up to one, and tiles it with the widest 128-multiple that
+    divides the padded width and whose VMEM footprint fits the budget: the
+    next wider divisor does not fit.  The XLA lane does not tile or pad."""
     from repro.core.coded_matmul import _plan_t_tiling
+    from repro.kernels.spmm_block import VMEM_TILE_BYTES, fused_vmem_bytes
 
-    assert _plan_t_tiling(128, "tpu") == (128, 128)   # aligned: no padding
-    assert _plan_t_tiling(384, "tpu") == (128, 384)
-    assert _plan_t_tiling(13, "tpu") == (128, 128)    # small prime
-    assert _plan_t_tiling(24, "tpu") == (128, 128)    # no 128-multiple divisor
-    assert _plan_t_tiling(251, "tpu") == (128, 256)   # prime > 128
-    assert _plan_t_tiling(2 * 127, "tpu") == (128, 256)
-    for bt in (13, 24, 251):
-        assert _plan_t_tiling(bt, "xla") == (bt, bt)
+    got = _plan_t_tiling(bt, "tpu", bs=bs, mn=mn, itemsize=itemsize)
+    assert got == want
+    t_tile, bt_pad = got
+    assert t_tile % 128 == 0 and bt_pad % t_tile == 0
+    assert fused_vmem_bytes(bs, mn, t_tile, itemsize) <= VMEM_TILE_BYTES
+    wider = [t for t in range(t_tile + 128, bt_pad + 1, 128) if bt_pad % t == 0]
+    assert all(fused_vmem_bytes(bs, mn, t, itemsize) > VMEM_TILE_BYTES
+               for t in wider)
+    assert _plan_t_tiling(bt, "xla", bs=bs, mn=mn, itemsize=itemsize) == (bt, bt)
+
+
+def test_plan_t_tiling_keeps_128_when_nothing_wider_fits(monkeypatch):
+    """A budget that no 256-wide tile fits leaves the 128-wide tile."""
+    from repro.core.coded_matmul import _plan_t_tiling
+    from repro.kernels import spmm_block
+
+    monkeypatch.setattr(spmm_block, "VMEM_TILE_BYTES",
+                        spmm_block.fused_vmem_bytes(128, 1, 256, 4) - 1)
+    assert _plan_t_tiling(16384, "tpu", bs=128, mn=1, itemsize=4) == (
+        128, 16384)
+
+
+@pytest.mark.parametrize("mn", [1, 2])
+def test_fused_decode_wide_tile_matches_xla_and_128(mn):
+    """The interpreted kernel at t_tile 256 over a 512-wide group (two
+    column tiles per group, two groups) agrees with the XLA lane within the
+    lanes' tolerance, and bit for bit with the same kernel at t_tile 128:
+    each output column's slots accumulate in the same order whatever the
+    tile width."""
+    vals, src, w, dvec, B = _fused_decode_case(seed=23, bt=512, mn=mn)
+    ref = ops.spmm_block_fused_decode(vals, src, w, dvec, B, bt=512, lane="xla")
+    wide = ops.spmm_block_fused_decode(vals, src, w, dvec, B, bt=512,
+                                       t_tile=256, lane="tpu")
+    narrow = ops.spmm_block_fused_decode(vals, src, w, dvec, B, bt=512,
+                                         t_tile=128, lane="tpu")
+    assert wide.shape == (mn, 4 * 8, 512)
+    np.testing.assert_allclose(np.asarray(wide), np.asarray(ref),
+                               atol=1e-4, rtol=1e-4)
+    np.testing.assert_array_equal(np.asarray(wide), np.asarray(narrow))
 
 
 def test_fused_decode_prime_bt_end_to_end(monkeypatch):

@@ -215,3 +215,32 @@ def test_two_threads_calling_one_op_keep_separate_parents(monkeypatch):
     for p in products:
         names = [s.name for s in spans if s.product == p.product]
         assert names.count(obs.UPLOAD) == names.count(obs.JIT) == 1
+
+
+def test_the_xla_lane_counts_no_kernel_grid_steps(case):
+    """The XLA lane runs no Pallas grid, so its products count no steps."""
+    for p in _products(case):
+        assert "kernel_grid_steps" not in p["counts"]
+    assert "kernel_grid_steps" not in case["counters"]
+
+
+def test_a_tpu_lane_product_counts_its_kernel_grid_steps(monkeypatch):
+    """On the TPU lane (the kernel interpreted here) a product's root
+    carries the launch's CB * (bt_pad / t_tile) * L grid steps.  A budget
+    that fits half of the 512-wide column group makes two column tiles."""
+    from repro.kernels import spmm_block
+
+    monkeypatch.setenv("REPRO_KERNEL_LANE", "tpu")
+    monkeypatch.setattr(spmm_block, "VMEM_TILE_BYTES",
+                        spmm_block.fused_vmem_bytes(8, 1, 256, 4))
+    monkeypatch.setattr(obs, "RECORDER", obs.Recorder())
+    op, A, _, ell = small_op(1, 1)
+    B = jnp.asarray(np.random.default_rng(6).standard_normal((32, 512)),
+                    jnp.float32)
+    C = op(A, B, a_sparse=ell)
+    np.testing.assert_allclose(np.asarray(C), np.asarray(A).T @ np.asarray(B),
+                               atol=1e-4, rtol=1e-4)
+    _, CB, L = op.pack_for(ell).wslot.shape
+    root, = (s for s in obs.records() if s.name == obs.PRODUCT)
+    assert root.counts["kernel_grid_steps"] == CB * 2 * L
+    assert obs.RECORDER.counters()["kernel_grid_steps"] == CB * 2 * L

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.coded import CodedMatmulConfig, plan
-from repro.core.coded_matmul import _make_block_sparse_fused_decode, pack_worker_tiles
+from repro.core.coded_matmul import (
+    _make_block_sparse_fused_decode, _plan_t_tiling, pack_worker_tiles)
 from repro.kernels.spmm_block import _spmm_block_fused_decode_pallas
 from repro.sparse import dense_to_block_ell
 
@@ -72,6 +73,28 @@ def test_fused_decode_kernel_smoke_width(one_chip, dtype):
             sds((CB, L), jnp.float32), sds((1,), jnp.float32),
             sds((s, bt), jnp.float32))
     fn = jax.jit(lambda *a: _spmm_block_fused_decode_pallas(*a, bt=bt))
+    assert _has_kernel(fn.lower(*args))
+
+
+@pytest.mark.parametrize("mn,dtype", [(1, jnp.float32), (2, jnp.float32),
+                                      (1, jnp.bfloat16), (1, jnp.int8)])
+def test_fused_decode_kernel_planned_tile_at_the_cells_shape(one_chip, mn,
+                                                             dtype):
+    """The benchmark cell's kernel -- 128 column blocks of 45 slots, bs =
+    128, a 16384-wide column group -- at the column tile the staging code
+    plans for it: wider than 128, and within the chip's default VMEM."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    CB, L, bs, s, bt = 128, 45, 128, 16384, 16384
+    t_tile, bt_pad = _plan_t_tiling(bt, "tpu", bs=bs, mn=mn,
+                                    itemsize=jnp.dtype(dtype).itemsize)
+    assert bt_pad == bt and t_tile > 128
+    args = (sds((CB, L, bs, bs), dtype), sds((CB, L, 2), jnp.int32),
+            sds((CB, L), jnp.float32), sds((mn,), jnp.float32),
+            sds((s, bt), jnp.float32))
+    fn = jax.jit(lambda *a: _spmm_block_fused_decode_pallas(
+        *a, bt=bt, t_tile=t_tile))
     assert _has_kernel(fn.lower(*args))
 
 
