@@ -13,10 +13,12 @@ any other key is refused:
 
 * ``b_operands`` -- how many dense B operands the closed loop cycles through;
 * ``warmup_products`` -- how many products set-up runs before the window;
-* ``membership`` (optional) -- ``healthy_products`` products with every
-  worker alive, then ``degraded_products`` with ``dead_workers`` workers dead,
-  and so on; each dead set is drawn from the seed among those whose loss
-  keeps the code decodable.
+* ``membership`` (optional) -- ``dead_workers``: how many workers straggle
+  in each product, drawn from the seed uniformly among all the workers, anew
+  for every product, as the paper's Section V experiments pick their
+  stragglers per job.  C is decoded from the other workers where they keep
+  the code decodable, and from every worker where they do not: the master
+  then has to wait for the straggler.
 
 This module uses NumPy only; the harness turns what it returns into device
 arrays.  Each use draws from its own stream of the seed, so adding a draw to
@@ -36,7 +38,7 @@ STREAMS = ("tiles", "b", "membership", "check")
 
 
 TRAFFIC_KEYS = {"name", "why", "b_operands", "warmup_products", "membership"}
-MEMBERSHIP_KEYS = {"healthy_products", "degraded_products", "dead_workers"}
+MEMBERSHIP_KEYS = {"dead_workers"}
 
 
 def load(path: pathlib.Path) -> dict:
@@ -101,43 +103,47 @@ def decodable_masks(coefficients: np.ndarray, dead_workers: int) -> list[np.ndar
 class Schedule:
     """Which B operand and which liveness mask product ``i`` of the loop uses.
 
-    ``mask(i)`` is None while every worker is alive.  The sequence is a
-    function of the traffic file, the code and the seed alone.
+    ``mask(i)`` is None where product ``i`` is decoded from every worker.
+    The sequence is a function of the traffic file, the code and the seed
+    alone.
     """
 
     def __init__(self, traffic: dict, rng: np.random.Generator,
                  coefficients: np.ndarray | None = None):
         self.b_operands = int(traffic.get("b_operands", 1))
         member = traffic.get("membership")
-        self.period = 1
-        self.healthy = 1
+        self.dead = 0
         self.masks: list[np.ndarray] = []
-        self._draws: list[int] = []
+        self._decodable: dict[tuple, np.ndarray] = {}
+        self._draws: list[np.ndarray | None] = []
         self._rng = rng
         if member:
             if coefficients is None:
                 raise ValueError("a membership schedule needs the code's "
                                  "coefficient matrix")
-            self.healthy = int(member["healthy_products"])
-            self.period = self.healthy + int(member["degraded_products"])
-            self.masks = decodable_masks(coefficients,
-                                         int(member["dead_workers"]))
+            self.dead = int(member["dead_workers"])
+            self.workers = coefficients.shape[0]
+            self.masks = decodable_masks(coefficients, self.dead)
             if not self.masks:
                 raise ValueError(
-                    f"no set of {member['dead_workers']} dead workers keeps "
-                    "the code decodable")
+                    f"no set of {self.dead} dead workers keeps the code "
+                    "decodable")
+            self._decodable = {tuple(m): m for m in self.masks}
 
     def b_index(self, i: int) -> int:
         return i % self.b_operands
 
     def mask(self, i: int) -> np.ndarray | None:
-        """The liveness mask of product ``i``, None when all are alive."""
-        if not self.masks or i % self.period < self.healthy:
+        """The liveness mask of product ``i``: its stragglers dead where the
+        rest decode C, else None (every worker)."""
+        if not self.dead:
             return None
-        cycle = i // self.period
-        while len(self._draws) <= cycle:
-            self._draws.append(int(self._rng.integers(len(self.masks))))
-        return self.masks[self._draws[cycle]]
+        while len(self._draws) <= i:
+            alive = np.ones(self.workers, bool)
+            alive[self._rng.choice(self.workers, size=self.dead,
+                                   replace=False)] = False
+            self._draws.append(self._decodable.get(tuple(alive)))
+        return self._draws[i]
 
     def warmup(self, products: int) -> list[tuple[int, np.ndarray | None]]:
         """The (B operand, mask) pairs of set-up's products: every operand and

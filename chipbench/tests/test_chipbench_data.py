@@ -59,6 +59,26 @@ def test_benchmark_names_units_and_bounds():
     assert len(json.dumps(BENCH)) < 64 * 1024
 
 
+def _named_files() -> dict:
+    """{directory under chipbench/: the files BENCHMARK.json names there}."""
+    return {
+        "configs": {cells.config_path(c) for c in BENCH["configs"]},
+        "traffic": {cells.traffic_path(w["traffic"]) for w in BENCH["workloads"]},
+        "metrics": {cells.metric_path(m["name"])
+                    for m in BENCH["end_to_end"] + BENCH["per_layer"]},
+    }
+
+
+@pytest.mark.parametrize("directory", ["configs", "traffic", "metrics"])
+def test_every_file_of_a_cell_is_named_by_the_benchmark(directory):
+    """No configuration, traffic mix or metric sits in the harness without
+    the BENCHMARK.json entry that runs it: a cell is added whole or not at
+    all."""
+    here = {p for p in (cells.HERE / directory).iterdir()
+            if p.suffix in (".json", ".py") and p.name != "__init__.py"}
+    assert here and here == _named_files()[directory]
+
+
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda e: e["name"])
 def test_config_reduced_keys_differ_from_the_paper(entry):
     with open(cells.config_path(entry)) as f:
@@ -92,34 +112,51 @@ def test_tile_pattern_is_seeded_and_every_seed_has_the_same_shape():
 
 
 def _churn_code():
-    """A 4-worker, m=2 code in which three single losses keep it decodable."""
-    return np.array([[1.0, 0.0], [0.0, 1.0], [1.0, 1.0], [1.0, -1.0]])
+    """A 4-worker, m=2 code as the cell's plan draws it: worker 0 alone holds
+    block 0, so its loss cannot be decoded and the other three can."""
+    return np.array([[4.0, 0.0], [0.0, 4.0], [0.0, 3.0], [0.0, 3.0]])
+
+
+def _churn_masks(seed, products):
+    churn = generator.load(cells.traffic_path("churn"))
+    sched = generator.Schedule(churn, generator.streams(seed)["membership"],
+                               _churn_code())
+    return sched, [sched.mask(i) for i in range(products)]
 
 
 @pytest.mark.parametrize("seed", SEEDS)
 def test_churn_schedule_names_only_decodable_masks_and_repeats(seed):
-    churn = generator.load(cells.traffic_path("churn"))
     code = _churn_code()
-
-    def masks(s):
-        sched = generator.Schedule(churn, generator.streams(s)["membership"], code)
-        return sched, [sched.mask(i) for i in range(64)]
-
-    sched, got = masks(seed)
-    _, again = masks(seed)
-    member = churn["membership"]
-    period = member["healthy_products"] + member["degraded_products"]
-    for i, (mask, other) in enumerate(zip(got, again)):
+    dead = generator.load(cells.traffic_path("churn"))["membership"]["dead_workers"]
+    sched, got = _churn_masks(seed, 64)
+    _, again = _churn_masks(seed, 64)
+    for mask, other in zip(got, again):
         assert (mask is None) == (other is None)
-        if i % period < member["healthy_products"]:
-            assert mask is None
+        if mask is None:
             continue
         np.testing.assert_array_equal(mask, other)
-        assert (~mask).sum() == member["dead_workers"]
+        assert (~mask).sum() == dead
         assert np.linalg.matrix_rank(code * mask[:, None]) == code.shape[1]
-    used = {tuple(m) for m in got if m is not None}
-    assert used <= {tuple(m) for m in sched.masks}
+    used = {None if m is None else tuple(m) for m in got}
+    # every product draws anew: each decodable loss and the undecodable one
+    # (decoded from every worker) all come up in 64 products
+    assert used == {None} | {tuple(m) for m in sched.masks}
     assert [sched.b_index(i) for i in range(4)] == [0, 1, 0, 1]
+    other_seed = [None if m is None else tuple(m)
+                  for m in _churn_masks(seed + 1, 64)[1]]
+    assert other_seed != [None if m is None else tuple(m) for m in got]
+
+
+def test_churn_stragglers_are_drawn_uniformly_over_every_worker():
+    """Each worker straggles in a quarter of the products, worker 0 among
+    them, whose products are decoded from every worker; consecutive products
+    share a straggler a quarter of the time."""
+    _, got = _churn_masks(SEEDS[0], 8000)
+    keys = [None if m is None else tuple(m) for m in got]
+    for key in set(keys):
+        assert keys.count(key) / len(keys) == pytest.approx(0.25, abs=0.02)
+    same = sum(a == b for a, b in zip(keys, keys[1:])) / (len(keys) - 1)
+    assert same == pytest.approx(0.25, abs=0.02)
 
 
 def test_decodable_masks_leave_out_a_loss_that_breaks_rank():

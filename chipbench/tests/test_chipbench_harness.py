@@ -17,19 +17,12 @@ from chipbench import cells
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 BENCH = cells.load_benchmark()
-#: a cell whose files are here but which waits for a four-chip measurement
-#: (PERF.md, Open questions); its CPU run covers the rebind and the exchange
-DEFERRED = {
-    "workloads": [{"name": "coded16k-w4.churn", "config": "coded16k-w4",
-                   "traffic": "churn", "chips": 4, "why": "deferred"}],
-    "configs": [{"name": "coded16k-w4", "file": "chipbench/configs/coded16k-w4.json"}],
-}
 #: the faults of the timed path each cell can have, and the control in the
 #: program's place: the exchange between chips exists only where there is
 #: more than one
 FAULTS = {w["name"]: ("unchanged", "half", "altered", "control")
           + (("exchange",) if w["chips"] > 1 else ())
-          for w in BENCH["workloads"] + DEFERRED["workloads"]}
+          for w in BENCH["workloads"]}
 
 
 def _env(**extra):
@@ -71,15 +64,12 @@ def test_harness_exits_nonzero_without_the_program(tmp_path):
 
 
 @pytest.fixture(scope="module")
-def fault_runs(tmp_path_factory):
+def fault_runs():
     """{workload: {fault: {"correct", "check"}}}, one process per cell."""
-    bench = dict(BENCH, **{k: BENCH[k] + DEFERRED[k] for k in DEFERRED})
-    path = tmp_path_factory.mktemp("bench") / "BENCHMARK.json"
-    path.write_text(json.dumps(bench))
     out = {}
     for workload, faults in FAULTS.items():
         proc = subprocess.run(
-            [sys.executable, "-m", "chipbench.tests.fault_run", str(path),
+            [sys.executable, "-m", "chipbench.tests.fault_run", str(cells.BENCHMARK),
              workload, "none", *faults],
             cwd=ROOT, env=_env(), capture_output=True, text=True, timeout=600)
         assert proc.returncode == 0, proc.stderr[-4000:]
