@@ -77,9 +77,12 @@ def check_config(config: dict, traffic: dict, chips: int, where: str):
     bs = config["block_size"]
     if config["s"] % bs or config["r"] % bs:
         raise ValueError(f"{where}: s and r must be multiples of block_size")
-    if config["num_workers"] != chips:
-        raise ValueError(f"{where}: {config['num_workers']} workers on {chips} "
-                         "chips; the mesh axis must equal the workers")
+    workers = config["num_workers"]
+    if workers < chips or workers % chips:
+        raise ValueError(f"{where}: {workers} workers on {chips} chips; the "
+                         "mesh axis of one device per chip splits the workers "
+                         "into equal contiguous runs, so their number must be "
+                         "a whole multiple of the chips")
     if set(config["limits"]) != LIMITS:
         raise ValueError(f"{where}: limits must name exactly {sorted(LIMITS)}")
     dead = (traffic.get("membership") or {}).get("dead_workers", 0)

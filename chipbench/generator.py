@@ -12,13 +12,25 @@ A traffic file (``traffic/<name>.json``) holds the loop's parameters only;
 any other key is refused:
 
 * ``b_operands`` -- how many dense B operands the closed loop cycles through;
-* ``warmup_products`` -- how many products set-up runs before the window;
+* ``warmup_products`` -- how many products set-up runs before the window
+  (``Schedule.warmup``);
 * ``membership`` (optional) -- ``dead_workers``: how many workers straggle
   in each product, drawn from the seed uniformly among all the workers, anew
   for every product, as the paper's Section V experiments pick their
   stragglers per job.  C is decoded from the other workers where they keep
   the code decodable, and from every worker where they do not: the master
   then has to wait for the straggler.
+
+Set-up's (B operand, mask) pairs are every B operand unmasked, then each
+decodable mask under the first operand (``Schedule.pairs``).  Where they
+number at most the configuration's ``check_products``, set-up runs every pair
+and then again in turn, ``warmup_products`` or the pairs' count, whichever is
+more.  Where they number more, as a code of many workers with several
+stragglers has hundreds of masks, set-up runs every B operand unmasked and
+then masks drawn from the seed without repeats, ``warmup_products`` in all
+(fewer where the masks run out): a window product under a mask set-up never
+ran is timed as it comes.  ``CheckSample`` bounds what the check keeps by the
+same rule.
 
 This module uses NumPy only; the harness turns what it returns into device
 arrays.  Each use draws from its own stream of the seed, so adding a draw to
@@ -34,7 +46,7 @@ import pathlib
 
 import numpy as np
 
-STREAMS = ("tiles", "b", "membership", "check")
+STREAMS = ("tiles", "b", "membership", "check", "warmup")
 
 
 TRAFFIC_KEYS = {"name", "why", "b_operands", "warmup_products", "membership"}
@@ -145,10 +157,60 @@ class Schedule:
             self._draws.append(self._decodable.get(tuple(alive)))
         return self._draws[i]
 
-    def warmup(self, products: int) -> list[tuple[int, np.ndarray | None]]:
-        """The (B operand, mask) pairs of set-up's products: every operand and
-        every mask the loop can use first, then again in turn up to
-        ``products``."""
-        pairs = [(b, None) for b in range(self.b_operands)]
-        pairs += [(0, m) for m in self.masks]
-        return [pairs[j % len(pairs)] for j in range(max(products, len(pairs)))]
+    @property
+    def pairs(self) -> list[tuple[int, np.ndarray | None]]:
+        """Every B operand unmasked, then each decodable mask under the first
+        operand: the pairs set-up can run."""
+        return ([(b, None) for b in range(self.b_operands)]
+                + [(0, m) for m in self.masks])
+
+    def warmup(self, products: int, limit: int,
+               rng: np.random.Generator) -> list[tuple[int, np.ndarray | None]]:
+        """The (B operand, mask) pairs of set-up's products.  Where the pairs
+        number at most ``limit``, every pair the loop can use first, then
+        again in turn up to ``products``; else every operand unmasked, then
+        masks drawn from ``rng`` without repeats up to ``products``."""
+        pairs = self.pairs
+        if len(pairs) <= limit:
+            return [pairs[j % len(pairs)]
+                    for j in range(max(products, len(pairs)))]
+        drawn = rng.choice(len(self.masks), replace=False,
+                           size=min(max(products - self.b_operands, 0),
+                                    len(self.masks)))
+        return pairs[:self.b_operands] + [(0, self.masks[k]) for k in drawn]
+
+
+#: the share of the window's products the check keeps beyond each pair's first
+SAMPLE_SHARE = 0.2
+
+
+class CheckSample:
+    """Which of the window's products the check keeps, and how much of each.
+
+    A product is kept where its (B operand, mask) pair is new to the window,
+    or where fewer than ``products`` are kept with columns and its seeded
+    draw falls under ``SAMPLE_SHARE``.  A kept product keeps its checked
+    columns and its projection (``"columns"``).  Where set-up's pairs number
+    more than ``products``, columns are kept for at most ``products``
+    products, and a new pair past them keeps its projection alone
+    (``"projection"``): every mask the window uses is still compared, and
+    what the check holds on the device stays bounded.
+    """
+
+    def __init__(self, products: int, pairs: int):
+        self.products = products
+        self.bounded = pairs > products
+        self.columns = 0
+        self._seen: set = set()
+
+    def take(self, pair: tuple, draw: float) -> str | None:
+        """What the check keeps of the next product: ``"columns"``,
+        ``"projection"`` or None."""
+        new = pair not in self._seen
+        if not (new or (self.columns < self.products and draw < SAMPLE_SHARE)):
+            return None
+        self._seen.add(pair)
+        if self.bounded and self.columns >= self.products:
+            return "projection"
+        self.columns += 1
+        return "columns"

@@ -1,7 +1,9 @@
 """The kernel's share of its roofline, in %: the least time the chip could
-take for the algorithm's work of one worker (``chipbench.work``: the live
-tiles its coded task needs, priced against the published peaks of the chip's
-``device_kind``) over the kernel's device time per product."""
+take for the algorithm's work of one chip (``chipbench.work.chip_work``: the
+live tiles the coded tasks of the workers it holds need, summed over those
+workers and averaged over the chips, priced against the published peaks of
+the chip's ``device_kind``) over the kernel's device time per product, which
+is also per chip."""
 
 from chipbench import xplane
 

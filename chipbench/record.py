@@ -15,8 +15,9 @@ class Run:
     to the first product under the new mask being ready; ``rebind_s`` the
     rebind call alone.  ``trace`` is the reduced profiler trace of a
     ``--trace 1`` run (``chipbench.xplane.TraceSummary``), ``work`` the
-    algorithm's mean work per worker (``chipbench.work``) and ``peaks`` the
-    chip's published peaks (``chipbench.peaks``).
+    algorithm's work per chip, the sum over the workers a chip holds, mean
+    over the chips (``chipbench.work.chip_work``), and ``peaks`` the chip's
+    published peaks (``chipbench.peaks``).
     """
 
     chips: int

@@ -3,13 +3,15 @@
     python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The run builds its operands from ``--seed`` (B on the device, A's tiles on
-the host as a BlockELL and A dense on the device from them), plans, binds
-and packs once through ``repro.coded``, warms up every (B operand, liveness
-mask) pair its traffic uses, and then runs a closed loop for ``--seconds``:
-one caller, each product timed from the call to ``block_until_ready`` of the
-decoded C.  Once the window has closed it compares a seeded sample of the
-window's products, every mask it used among them, with the plain reference,
-and prints the result as the last line of standard output.  ``--trace 1``
+the host as a BlockELL and A dense on the device from them), plans the
+configuration's ``num_workers`` workers and binds them to a mesh of one
+device per chip of the cell, packs once through ``repro.coded``, warms up the
+(B operand, liveness mask) pairs its traffic uses (``generator.Schedule``),
+and then runs a closed loop for ``--seconds``: one caller, each product timed
+from the call to ``block_until_ready`` of the decoded C.  Once the window has
+closed it compares a seeded sample of the window's products, every mask it
+used among them (``generator.CheckSample``), with the plain reference, and
+prints the result as the last line of standard output.  ``--trace 1``
 records a profiler trace of the window and prints the per-layer metrics in
 place of the end-to-end ones.
 
@@ -200,23 +202,28 @@ def _mask_key(mask) -> tuple | None:
 
 def check_kept(kept, refs, device, limits) -> tuple[dict, int]:
     """The worst gap of every compared number over the kept products, on
-    every chip's copy, and how many products failed a limit."""
+    every chip's copy, and how many products failed a limit: ``proj_max``
+    on every kept product, ``err_max`` and ``err_fro`` on those that kept
+    their columns (``cut`` not None)."""
     import jax
 
     from chipbench import reference
 
     worst = {name: 0.0 for name in limits}
     failed = 0
-    for b, _, (cut, proj) in kept:
+    for b, _, cut, proj in kept:
         bad = False
-        for c_shard, p_shard in zip(cut.addressable_shards,
-                                    proj.addressable_shards):
-            g = reference.gaps(jax.device_put(c_shard.data, device), refs[b][0])
-            g["proj_max"] = reference.gaps(jax.device_put(p_shard.data, device),
-                                           refs[b][1])["err_max"]
-            for name, limit in limits.items():
-                worst[name] = max(worst[name], g[name])
-                bad |= limit is None or not g[name] <= limit
+        for j, p_shard in enumerate(proj.addressable_shards):
+            g = {"proj_max": reference.gaps(jax.device_put(p_shard.data, device),
+                                            refs[b][1])["err_max"]}
+            if cut is not None:
+                g.update(reference.gaps(
+                    jax.device_put(cut.addressable_shards[j].data, device),
+                    refs[b][0]))
+            for name, value in g.items():
+                limit = limits[name]
+                worst[name] = max(worst[name], value)
+                bad |= limit is None or not value <= limit
         failed += bad
     return worst, failed
 
@@ -244,7 +251,9 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
     enable_compile_cache()
     counter = compile_counter()
     N, m, n = config["num_workers"], config["m"], config["n"]
-    mesh = compat.make_mesh((N,), ("model",), devices=devices)
+    # One device per chip, N / chips workers to each: a program that cannot
+    # place them refuses at bind, and the run ends on its error.
+    mesh = compat.make_mesh((cell.chips,), ("model",), devices=devices)
     rng = generator.streams(seed)
 
     # -- set-up: operands, plan, bind, pack, warm-up -------------------------
@@ -266,10 +275,10 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
         keep = jax.jit(_keep)
         cols_dev, V_dev = jax.device_put((ops_.cols, ops_.V),
                                          ops_.A.sharding)
-    # Set-up runs the traffic's ``warmup_products``: every (B operand, mask)
-    # pair the window uses, and then again in turn.
+    max_kept = int(config["check_products"])
     warm_ms = []
-    for b, mask in schedule.warmup(int(traffic_.get("warmup_products", 0))):
+    for b, mask in schedule.warmup(int(traffic_.get("warmup_products", 0)),
+                                   max_kept, rng["warmup"]):
         t0 = time.perf_counter()
         C = base.with_survivors(mask)(ops_.A, ops_.B[b], a_sparse=ops_.ell)
         warm_ms.append((time.perf_counter() - t0) * 1e3)
@@ -286,8 +295,8 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
         options.python_tracer_level = 0
         jax.profiler.start_trace(tracedir, profiler_options=options)
     check_rng = rng["check"]
-    max_kept = int(config["check_products"])
-    kept, seen = [], set()
+    sample = generator.CheckSample(max_kept, len(schedule.pairs))
+    kept = []
     product_s, stage_s, recover_s, rebind_s = [], [], [], []
     op, cur = base, None
     compiles0 = counter.compiles
@@ -319,11 +328,15 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
             stage_s.append(t1 - t0)
             if changed:
                 recover_s.append(t2 - t_r)
-            draw = check_rng.random()
-            if (b, key) not in seen or (len(kept) < max_kept and draw < 0.2):
-                seen.add((b, key))
+            take = sample.take((b, key), check_rng.random())
+            if take is not None:
+                # One program keeps both parts, so a projection kept alone
+                # is the one a product with columns keeps.
                 with span("check"):
-                    kept.append((b, key, keep(C, cols_dev, V_dev)))
+                    cut, proj = keep(C, cols_dev, V_dev)
+                    kept.append((b, key, cut if take == "columns" else None,
+                                 proj))
+                    del cut
             del C
             i += 1
     t_win1 = time.perf_counter()
@@ -366,7 +379,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
             list(ops_.idx), s=config["s"], r=config["r"],
             t=config["t"], block_size=config["block_size"],
             tile_itemsize=np.dtype(config["compute_dtype"]).itemsize)
-        run.work = work.mean_work(per_worker)
+        run.work = work.chip_work(per_worker, cell.chips)
         summary = xplane.reduce(xplane.find_xspace(tracedir), WINDOW_SPAN,
                                 spans=SPANS)
         shutil.rmtree(tracedir, ignore_errors=True)

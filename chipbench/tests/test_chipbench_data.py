@@ -27,7 +27,8 @@ def test_workload_resolves_to_its_files_by_name(workload):
     assert cells.config_path(entry).is_relative_to(cells.HERE)
     assert cells.traffic_path(w["traffic"]).is_file()
     assert cell.config["name"] == w["config"]
-    assert cell.chips == w["chips"] == cell.config["num_workers"]
+    assert cell.chips == w["chips"]
+    assert cell.config["num_workers"] % cell.chips == 0
     names = [m["name"] for m in cell.end_to_end]
     assert "setup_s" in names and len(names) >= 2
     assert cell.per_layer
@@ -192,11 +193,16 @@ def test_a_traffic_key_the_generator_does_not_read_is_refused(tmp_path, extra):
         generator.load(_write(tmp_path, dict({"name": "x", "b_operands": 2}, **extra)))
 
 
-@pytest.mark.parametrize("change", [
-    {"precision": "high"}, {"distribution": "robust_soliton"}, {"nnz_b": 7},
-    {"decode": "gather"}, {"num_workers": 2}, {"limits": {"err_max": 1.0}}])
-def test_a_config_value_the_harness_does_not_run_is_refused(change):
-    w = next(x for x in BENCH["workloads"] if x["chips"] == 1)
+#: (the chips of the cell whose configuration is changed, the change)
+REFUSED = [(1, {"precision": "high"}), (1, {"distribution": "robust_soliton"}),
+           (1, {"nnz_b": 7}), (1, {"decode": "gather"}),
+           (4, {"num_workers": 6}), (1, {"limits": {"err_max": 1.0}})]
+
+
+@pytest.mark.parametrize("chips,change", REFUSED,
+                         ids=[f"change{j}" for j in range(len(REFUSED))])
+def test_a_config_value_the_harness_does_not_run_is_refused(chips, change):
+    w = next(x for x in BENCH["workloads"] if x["chips"] == chips)
     entry = next(c for c in BENCH["configs"] if c["name"] == w["config"])
     with open(cells.config_path(entry)) as f:
         config = dict(json.load(f), **change)
@@ -213,3 +219,25 @@ def test_a_traffic_that_kills_more_than_the_code_holds_is_refused():
     churn = generator.load(cells.traffic_path("churn"))
     with pytest.raises(ValueError, match="held to 0"):
         cells.check_config(config, churn, 1, entry["file"])
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_workers_a_whole_multiple_of_the_chips_are_accepted(chips):
+    """20 workers, the m = n = 4 code with two stragglers, fit one chip and a
+    2x2 host; no count that is not a whole multiple of the chips is taken."""
+    w = next(x for x in BENCH["workloads"] if x["chips"] == 4)
+    entry = next(c for c in BENCH["configs"] if c["name"] == w["config"])
+    with open(cells.config_path(entry)) as f:
+        config = dict(json.load(f), m=4, n=4, num_stragglers=2)
+    traffic = generator.load(cells.traffic_path(w["traffic"]))
+    accepted = []
+    for workers in range(25):
+        try:
+            cells.check_config(dict(config, num_workers=workers), traffic,
+                               chips, entry["file"])
+        except ValueError as e:
+            assert "whole multiple of the chips" in str(e)
+        else:
+            accepted.append(workers)
+    assert 20 in accepted
+    assert accepted == list(range(chips, 25, chips))

@@ -63,6 +63,66 @@ def test_harness_exits_nonzero_without_the_program(tmp_path):
     assert "repro" in proc.stderr
 
 
+#: run_cell on a configuration given as a file, with the traffic given as
+#: JSON, on one host device; the mesh run_cell builds is reported on stderr,
+#: and a result line is printed only where the run ends
+MANY_WORKERS_RUN = """
+import json, sys
+from unittest import mock
+sys.path[:0] = ['.', 'src']
+from chipbench import cells, run as harness
+from repro import compat
+
+with open(sys.argv[1]) as f:
+    config = json.load(f)
+traffic = json.loads(sys.argv[2])
+cells.check_config(config, traffic, 1, sys.argv[1])
+cell = cells.Cell(name='scratch', chips=1, config=config, traffic=traffic,
+                  end_to_end=(), per_layer=())
+make_mesh = compat.make_mesh
+
+def spy(*args, **kw):
+    mesh = make_mesh(*args, **kw)
+    print('mesh', json.dumps(dict(mesh.shape)), file=sys.stderr, flush=True)
+    return mesh
+
+with mock.patch.object(compat, 'make_mesh', spy):
+    result, _ = harness.run_cell(cell, 2 ** 31 + 5, 1.0, False)
+print(json.dumps(result))
+"""
+
+
+def test_many_workers_on_one_chip_reach_the_program(tmp_path):
+    """The m = n = 4 code on 20 workers, two stragglers a product, on one
+    device: the harness takes the configuration and builds a one-device
+    mesh.  A program that cannot place 20 workers on it refuses with its own
+    error and the run prints no result; one that can, runs it correctly."""
+    entry = next(c for c in BENCH["configs"] if c["name"] == "coded16k-w4")
+    with open(ROOT / entry["file"]) as f:
+        config = dict(json.load(f), m=4, n=4, num_workers=20, num_stragglers=2,
+                      s=512, r=512, t=512, nnz_a=7, nnz_b=512 * 512,
+                      check_columns=64)
+    traffic = {"name": "scratch", "b_operands": 2, "warmup_products": 4,
+               "membership": {"dead_workers": 2}}
+    cells.check_config(config, traffic, 1, "scratch")
+    path = tmp_path / "many-workers.json"
+    path.write_text(json.dumps(config))
+    proc = subprocess.run(
+        [sys.executable, "-c", MANY_WORKERS_RUN, str(path), json.dumps(traffic)],
+        cwd=ROOT, env=_env(), capture_output=True, text=True, timeout=600)
+    meshes = [json.loads(line[len("mesh "):])
+              for line in proc.stderr.splitlines() if line.startswith("mesh ")]
+    assert meshes == [{"model": 1}], proc.stderr[-4000:]
+    if proc.returncode == 0:
+        assert json.loads(proc.stdout.strip().splitlines()[-1])["correct"]
+        return
+    assert proc.stdout == ""
+    last = proc.stderr.strip().splitlines()
+    frames = [line for line in last if line.lstrip().startswith("File ")]
+    assert "src/repro/" in frames[-1] and "chipbench" not in frames[-1], frames
+    assert last[-1].startswith("ValueError: ") and "workers 20" in last[-1]
+
+
 @pytest.fixture(scope="module")
 def fault_runs():
     """{workload: {fault: {"correct", "check"}}}, one process per cell."""

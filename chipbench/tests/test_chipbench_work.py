@@ -42,8 +42,11 @@ def test_work_matches_a_hand_count():
     # worker 2: no live slot, the decode combine and its result alone
     assert got[2]["tiles"] == 0
     assert got[2]["bytes"] == br * bt * 4
-    mean = work.mean_work(got)
-    assert mean["flops"] == pytest.approx(sum(w["flops"] for w in got) / 3)
+    # one worker a chip: the work of a chip is the mean over the workers
+    per_chip = work.chip_work(got, 3)
+    for key in ("flops", "bytes"):
+        assert per_chip[key] == float(np.mean([w[key] for w in got]))
+    assert per_chip["flops"] == pytest.approx(sum(w["flops"] for w in got) / 3)
 
 
 def test_work_prices_the_stored_itemsize():
@@ -97,6 +100,47 @@ def test_work_is_unchanged_when_the_plan_is_packed_in_another_slot_order():
     # the packs hold exactly the tiles the count prices, slot by slot
     assert [w["tiles"] for w in got_p] == pack_p.live_tiles.tolist()
     assert [w["tiles"] for w in got_q] == pack_q.live_tiles.tolist()
+
+
+def test_chip_work_sums_each_chips_contiguous_workers():
+    """8 workers on 2 chips: chip 0 holds workers 0-3, chip 1 workers 4-7,
+    as ``P("model")`` splits the leading worker axis."""
+    per_worker = [{"flops": 10.0 ** k, "bytes": 3.0 * k, "tiles": k}
+                  for k in range(8)]
+    got = work.chip_work(per_worker, 2)
+    assert got["flops"] == (1111.0 + 11110000.0) / 2
+    assert got["bytes"] == (3.0 * (0 + 1 + 2 + 3) + 3.0 * (4 + 5 + 6 + 7)) / 2
+    with pytest.raises(ValueError):
+        work.chip_work(per_worker, 3)
+
+
+def _traced(work_, kernel_s_per_chip, chips):
+    """A run of 10 products whose trace holds the kernel for the given
+    seconds on each chip."""
+    from chipbench.record import Run
+
+    kernel = "spmm_block_fused_decode.1"
+    trace = xplane.TraceSummary(
+        window_s=1.0, chips=chips, busy_s=kernel_s_per_chip,
+        op_s=[{kernel: kernel_s_per_chip} for _ in range(chips)],
+        op_count=[{kernel: 10} for _ in range(chips)], idle_by_span={})
+    return Run(chips=chips, setup_s=1.0, window_s=1.0, product_s=[0.1] * 10,
+               stage_s=[0.05] * 10, recover_s=[], rebind_s=[], compiles=10,
+               work=work_, peaks=peaks.lookup("TPU v5 lite"), trace=trace)
+
+
+@pytest.mark.parametrize("per_chip", [2, 4])
+def test_kernel_roofline_reads_the_work_of_every_worker_a_chip_holds(per_chip):
+    """At the same kernel time, a chip that holds k workers does k times the
+    work of one, so its roofline share reads k times as high."""
+    cols, weights, tile_rows = _tiny()
+    one = work.worker_work(cols, weights, 2, 1, tile_rows, s=8, r=8, t=6,
+                           block_size=BS)[0]
+    single = _traced(work.chip_work([one] * 2, 2), 0.5, 2)
+    many = _traced(work.chip_work([one] * (2 * per_chip), 2), 0.5, 2)
+    assert single.read("kernel_ms") == many.read("kernel_ms") == 50.0
+    assert many.read("kernel_roofline") == pytest.approx(
+        per_chip * single.read("kernel_roofline"), rel=1e-12)
 
 
 def test_peaks_are_looked_up_by_device_kind_and_unknown_kinds_fail():

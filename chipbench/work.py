@@ -1,4 +1,5 @@
-"""The coded product's algorithmic work per worker: the roofline's numerator.
+"""The coded product's algorithmic work per worker, and per chip: the
+roofline's numerator.
 
 Worker k's coded task is ``sum_l w_kl A_{i_l}^T B_{j_l}`` over its live task
 slots (``w_kl != 0``), followed by the decode combine of its (br, bt) result
@@ -57,7 +58,14 @@ def worker_work(cols, weights, m: int, n: int, tile_rows, *, s: int, r: int,
     return out
 
 
-def mean_work(per_worker: list[dict]) -> dict:
-    """Mean flops and bytes over the workers (one worker per chip)."""
-    return {key: float(np.mean([w[key] for w in per_worker]))
+def chip_work(per_worker: list[dict], chips: int) -> dict:
+    """Flops and bytes of one chip, mean over the chips: each chip's sum over
+    the workers it holds.  ``P("model")`` splits the (N, ...) worker axis over
+    the ``chips`` devices in contiguous runs of N / chips, so chip c holds
+    workers c * N / chips to (c + 1) * N / chips - 1."""
+    if len(per_worker) % chips:
+        raise ValueError(f"{len(per_worker)} workers do not split over "
+                         f"{chips} chips")
+    return {key: float(np.mean(np.reshape([w[key] for w in per_worker],
+                                          (chips, -1)).sum(axis=1)))
             for key in ("flops", "bytes")}
