@@ -55,8 +55,11 @@ TPU adaptation notes (DESIGN.md section 3):
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import threading
 import warnings
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -505,6 +508,8 @@ def _block_sparse_operands(plan: CodedMatmulPlan, pack: WorkerTilePack,
             B_.reshape(s_, t_ // bt, bt),
             ((0, 0), (0, 0), (0, bt_pad - bt))).reshape(s_, -1)
 
+    # the pack's own arrays first: stage_coded_matmul keeps their device
+    # copies between calls and puts only the weights on the devices
     arrays = (pack.vals, pack.src, wsl_all.astype(np.float32))
     return arrays, lane, t_tile, bt_pad, pad_cols
 
@@ -628,6 +633,11 @@ def resolve_pack(
     return pack
 
 
+def _fuses(entry: coded_backends.Backend) -> bool:
+    """Whether staging folds the decode into the backend's local product."""
+    return entry.fused_decode and entry.fused_local_product_factory is not None
+
+
 @obs.span(obs.PREPARE)
 def build_coded_program(
     plan: CodedMatmulPlan,
@@ -649,7 +659,9 @@ def build_coded_program(
     the backend's per-worker operands (task table or tile pack).  The
     program takes them sharded over ``axis_name``, so each device holds
     only its own worker's rows and nothing is baked into the compiled
-    program as a constant.  ``bt`` is the column-group width t / n.
+    program as a constant: the program depends only on the geometry
+    ``_program_key`` lists, so one program serves every plan and mask of
+    it.  ``bt`` is the column-group width t / n.
     """
     entry = coded_backends.get_backend(backend)
     if entry.virtual:
@@ -666,7 +678,7 @@ def build_coded_program(
         raise ValueError(
             f"backend {backend!r} is registered but has no "
             "local_product_factory attached")
-    fuse = entry.fused_decode and entry.fused_local_product_factory is not None
+    fuse = _fuses(entry)
     factory = (entry.fused_local_product_factory if fuse
                else entry.local_product_factory)
     backend_arrays, local_product = factory(plan, pack, bt)
@@ -721,12 +733,128 @@ def build_coded_program(
     return program, worker_arrays
 
 
+#: programs, and packs' device copies, each staging cache keeps (the runtime
+#: pack cache keeps as many packs)
+STAGED_ENTRIES = 16
+
+
+class StagedPrograms:
+    """The programs staged so far, and the device copies of the tile packs
+    they ran on, each an LRU of ``max_entries``.
+
+    A program is keyed on its static geometry (``_program_key``), never on
+    the survivor mask, the decode matrix or the plan's weights: those enter
+    as operands, so one compiled program serves every mask and every
+    chunk-progress rebind of a geometry.  A pack's ``vals`` and ``src`` are
+    put on the devices once per (pack, sharding) and then reused.  A copy
+    lives no longer than its host pack: it is dropped the moment the pack
+    dies, so a pack the runtime pack cache evicts or clears, and that no op
+    still holds, takes its device copy with it.
+    """
+
+    def __init__(self, max_entries: int = STAGED_ENTRIES):
+        self.max_entries = max_entries
+        self._programs: collections.OrderedDict = collections.OrderedDict()
+        # (id(pack), sharding) -> (the pack's finalizer, (vals, src) copies)
+        self._packs: collections.OrderedDict = collections.OrderedDict()
+        # re-entrant: a pack that dies while this thread holds the lock
+        # drops its entry from inside it
+        self._lock = threading.RLock()
+
+    def program(self, key, program):
+        """(the jitted program staged under ``key``, whether it was staged
+        before); on a miss ``program`` is jitted and kept."""
+        with self._lock:
+            jitted = self._programs.get(key)
+            if jitted is not None:
+                self._programs.move_to_end(key)
+                return jitted, True
+            self._programs[key] = jitted = jax.jit(program)
+            while len(self._programs) > self.max_entries:
+                self._programs.popitem(last=False)
+            return jitted, False
+
+    def device_pack(self, pack: WorkerTilePack, sharding):
+        """(the device copies of the pack's ``vals`` and ``src`` placed by
+        ``sharding``, whether this call put them on the devices)."""
+        key = (id(pack), sharding)
+        with self._lock:
+            entry = self._packs.get(key)
+            if entry is not None:
+                self._packs.move_to_end(key)
+                return entry[1], False
+        copies = (jax.device_put(pack.vals, sharding),
+                  jax.device_put(pack.src, sharding))
+        # the entry goes with its pack, so a later pack never finds it
+        # under a reused id
+        gone = weakref.finalize(pack, self._drop, key)
+        with self._lock:
+            dropped = [self._packs.pop(key, None)]
+            self._packs[key] = (gone, copies)
+            while len(self._packs) > self.max_entries:
+                dropped.append(self._packs.popitem(last=False)[1])
+        for entry in filter(None, dropped):
+            entry[0].detach()
+        return copies, True
+
+    def _drop(self, key) -> None:
+        with self._lock:
+            self._packs.pop(key, None)
+
+    def stats(self) -> dict:
+        with self._lock:
+            return {"programs": len(self._programs),
+                    "device_packs": len(self._packs)}
+
+    def clear(self) -> None:
+        with self._lock:
+            for gone, _ in self._packs.values():
+                gone.detach()
+            self._programs.clear()
+            self._packs.clear()
+
+
+#: the process-wide staging cache every ``stage_coded_matmul`` call shares
+STAGED = StagedPrograms()
+
+
+def _traced(*operands) -> bool:
+    return any(isinstance(x, jax.core.Tracer) for x in operands)
+
+
+def _program_key(plan: CodedMatmulPlan, mesh, bt: int, *,
+                 axis_name: str = "model", alive=None,
+                 out_dtype=jnp.float32, backend: str = "dense_scan",
+                 pack: WorkerTilePack | None = None,
+                 out_sharded: bool = False) -> tuple:
+    """What the program ``build_coded_program`` returns closes over, so
+    equal keys stage the same program.  ``alive`` is not in it: the decode
+    columns and the plan's weights are operands."""
+    entry = coded_backends.get_backend(backend)
+    tiles = None
+    if entry.needs_pack:
+        lane, t_tile, bt_pad = _kernel_grid(plan, pack, bt)
+        tiles = (lane, t_tile, bt_pad,
+                 lane == "tpu" and ops.resolve_interpret(),
+                 pack.block_size, pack.vals.shape, pack.vals.dtype,
+                 pack.src.shape, pack.wslot.shape)
+    return (backend, _fuses(entry), plan.m, plan.n, mesh.shape[axis_name],
+            bt, out_sharded, jnp.dtype(out_dtype), axis_name, mesh, tiles)
+
+
 def _jitted_program(A, B, plan, mesh, axis_name, **kwargs):
+    """(the jitted program, its worker arrays on the host, their sharding,
+    whether the program was staged before).  Under an outer trace the
+    program is jitted afresh and nothing is kept."""
     *_, bt = _check_operands(A, B, plan, mesh, axis_name)
     program, worker_arrays = build_coded_program(
         plan, mesh, bt, axis_name=axis_name, **kwargs)
     by_worker = jax.sharding.NamedSharding(mesh, P(axis_name))
-    return jax.jit(program), worker_arrays, by_worker
+    if _traced(A, B):
+        return jax.jit(program), worker_arrays, by_worker, False
+    jitted, hit = STAGED.program(
+        _program_key(plan, mesh, bt, axis_name=axis_name, **kwargs), program)
+    return jitted, worker_arrays, by_worker, hit
 
 
 def stage_coded_matmul(
@@ -738,7 +866,7 @@ def stage_coded_matmul(
     axis_name: str = "model",
     **kwargs,
 ) -> jax.Array:
-    """Compile and run the program of one coded matmul (the shared core).
+    """Run the program of one coded matmul (the shared core).
 
     Keyword arguments are those of ``build_coded_program``.  ``plan`` must
     already be survivor-adjusted (its decode matrix re-derived via
@@ -748,14 +876,39 @@ def stage_coded_matmul(
     and ``repro.coded.CodedOp`` funnel through here, which is what makes
     old-vs-new bit-parity structural rather than coincidental.  The
     per-worker operands are placed one worker per device along
-    ``axis_name``.  Each call stages a fresh program, so each call compiles.
+    ``axis_name``.
+
+    The program is staged once per geometry and kept in ``STAGED``, with
+    the device copy of the pack's ``vals`` and ``src``: a later call with
+    the same geometry and pack, whatever its survivor mask, uploads only
+    the decode columns and the plan-dependent arrays (the slot weights, or
+    dense_scan's task table) and runs the compiled program.  Each call
+    counts ``program_hits`` where it reused both, else ``program_misses``.
+    Under an outer trace (``A`` or ``B`` a tracer) each call stages afresh
+    and nothing is kept.
     """
-    program, worker_arrays, by_worker = _jitted_program(
+    program, worker_arrays, by_worker, hit = _jitted_program(
         A, B, plan, mesh, axis_name, **kwargs)
+    pack = kwargs.get("pack")
+    needs_pack = coded_backends.get_backend(
+        kwargs.get("backend", "dense_scan")).needs_pack
+    keep = needs_pack and not _traced(A, B)
     with obs.span(obs.UPLOAD):
-        arrays = [jax.device_put(a, by_worker) for a in worker_arrays]
-    obs.count("upload_bytes", sum(a.nbytes for a in worker_arrays))
-    if coded_backends.get_backend(kwargs.get("backend", "dense_scan")).needs_pack:
+        if keep:
+            # (dvecs, pack.vals, pack.src, wsl), as _block_sparse_operands
+            # orders them: the pack's own two stay on the devices
+            dvecs, _, _, *rest = worker_arrays
+            resident, fresh = STAGED.device_pack(pack, by_worker)
+            per_call = (dvecs, *rest)
+            put = [jax.device_put(a, by_worker) for a in per_call]
+            arrays = [put[0], *resident, *put[1:]]
+        else:
+            fresh, per_call = False, worker_arrays
+            arrays = [jax.device_put(a, by_worker) for a in per_call]
+    obs.count("upload_bytes", sum(a.nbytes for a in per_call) + (
+        pack.vals.nbytes + pack.src.nbytes if fresh else 0))
+    obs.count("program_hits" if hit and not fresh else "program_misses")
+    if needs_pack:
         steps = _kernel_grid_steps(plan, kwargs["pack"], B.shape[1] // plan.n)
         if steps:
             obs.count("kernel_grid_steps", steps)
@@ -765,13 +918,14 @@ def stage_coded_matmul(
 
 def lower_coded_matmul(A, B, plan: CodedMatmulPlan, mesh: jax.sharding.Mesh,
                        *, axis_name: str = "model", **kwargs):
-    """``jax.stages.Lowered`` of the program ``stage_coded_matmul`` runs.
+    """``jax.stages.Lowered`` of the program ``stage_coded_matmul`` runs
+    (the same staged program, from ``STAGED``).
 
     ``A`` and ``B`` may be arrays or ``jax.ShapeDtypeStruct``s (with their
     shardings); the per-worker operands enter as shapes sharded by worker,
     so ``.compile()`` works for a described topology with no chip attached.
     """
-    program, worker_arrays, by_worker = _jitted_program(
+    program, worker_arrays, by_worker, _ = _jitted_program(
         A, B, plan, mesh, axis_name, **kwargs)
     return program.lower(A, B, *(
         jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=by_worker)

@@ -9,8 +9,8 @@ spans, each in the module where its work happens:
                          ``resolve_pack`` (``CodedOp._staging_kwargs``), and
                          the per-call weight gather, decode columns and
                          ``shard_map`` (``build_coded_program``)
-``repro.stage.upload``   the ``device_put`` of the worker arrays
-                         (``stage_coded_matmul``)
+``repro.stage.upload``   the ``device_put`` of the worker arrays not yet on
+                         the devices (``stage_coded_matmul``)
 ``repro.stage.jit``      the jitted call until it returns; its self time is
                          the enqueue
 ``repro.stage.lower``    JAX's trace and lowering to MLIR, child of the jit
@@ -23,10 +23,13 @@ spans, each in the module where its work happens:
 
 Counters are process-wide integers, incremented at the same boundaries:
 ``products``, ``upload_bytes`` (the ``nbytes`` of the worker arrays put on
-the devices), ``kernel_grid_steps`` (the grid steps of one worker's
-block-sparse kernel launch, on the TPU lane only), ``compiles`` (backend
-compiles inside a product, read-backs included), ``readbacks`` (executables
-read back from the persistent cache inside a product) and ``rebinds``.  A
+the devices in the call: a pack's tiles on its first call, then only the
+per-call decode columns and weights), ``program_hits`` (the call reused a
+staged program and the pack's device copy) and ``program_misses`` (it built
+either), ``kernel_grid_steps`` (the grid steps of one worker's block-sparse
+kernel launch, on the TPU lane only), ``compiles`` (backend compiles inside
+a product, read-backs included), ``readbacks`` (executables read back from
+the persistent cache inside a product) and ``rebinds``.  A
 product's span also carries the counts that moved while it was open, so a
 window of products can be read without a counter snapshot at its start.
 
@@ -53,8 +56,9 @@ import time
 
 import jax
 
-#: spans kept: about 500 products of 7 spans, and their rebinds
-RING = 4096
+#: spans kept: a window of 2800 products of 5 spans and a rebind each, a
+#: 51 s run at 18 ms a product (a first call's lower and compile add 2)
+RING = 16384
 
 PRODUCT = "repro.product"
 PREPARE = "repro.stage.prepare"

@@ -18,14 +18,22 @@ BASE plan, so survivor rebinds of the same op share one pack.  The cache is
 a ``PackCache`` object with hit/miss/eviction counters; the module-level
 functions operate on the process-wide default instance (``GLOBAL``), whose
 ``stats()`` snapshot rides along in ``ExecutionReport.decode_stats`` so
-multi-job cache sharing is auditable from any report.
+multi-job cache sharing is auditable from any report.  A pack's device copy
+(``coded_matmul.STAGED``) lives as long as the pack, so a pack this cache
+evicts or clears, and that no op still holds, frees it.  ``clear()`` is the
+process's cold start: it also empties the staged programs.
 """
 
 from __future__ import annotations
 
 from collections import OrderedDict
 
-from repro.core.coded_matmul import CodedMatmulPlan, WorkerTilePack, pack_worker_tiles
+from repro.core.coded_matmul import (
+    STAGED,
+    CodedMatmulPlan,
+    WorkerTilePack,
+    pack_worker_tiles,
+)
 from repro.sparse.blocksparse import BlockELL
 
 _MAX_ENTRIES = 16
@@ -89,4 +97,7 @@ def cache_stats() -> dict:
 
 
 def clear() -> None:
+    """Empty the process-wide cache and the staged programs, so the next
+    product packs, uploads and lowers afresh."""
     GLOBAL.clear()
+    STAGED.clear()

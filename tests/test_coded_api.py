@@ -394,3 +394,304 @@ def test_top_level_exports():
     assert callable(repro.run_device_job)
     for name in repro.__all__:
         assert getattr(repro, name) is not None
+
+
+# ------------------- staging: the program and pack are kept -------------------
+
+_FOUR_DEVICES = r"""
+import json, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+sys.path.insert(0, sys.argv[1])
+import test_coded_api
+print(json.dumps(test_coded_api.cached_and_fresh()))
+"""
+
+
+def _masks(op) -> list:
+    """None, each decodable single-loss mask, and one decodable (N, 2)
+    chunk-progress mask that leaves a worker's second chunk out."""
+    N = op.num_workers
+    masks = [None]
+    for k in range(N):
+        alive = np.ones(N, bool)
+        alive[k] = False
+        try:
+            op.with_survivors(alive)
+        except DecodingError:
+            continue
+        masks.append(alive)
+    for k in range(N):
+        chunks = np.ones((N, 2), bool)
+        chunks[k, 1] = False
+        try:
+            rebound = op.with_survivors(chunks)
+        except DecodingError:
+            continue
+        if not np.array_equal(rebound.plan_.weights, op.base_plan.weights):
+            masks.append(chunks)
+            break
+    return masks
+
+
+def cached_and_fresh() -> dict:
+    """On four host devices, for each backend and decode layout: whether
+    each mask's product from the kept program equals the product staged
+    afresh for it bit for bit, and whether the kept program's calls all
+    counted ``program_hits``.  The psum layout runs m = 2, n = 1 over N = 4
+    workers; the reduce-scatter layout m = 4, n = 1, since it splits the
+    mn decoded blocks N ways (with mn = N no single loss decodes)."""
+    from repro import obs
+    from repro.core.coded_matmul import STAGED
+
+    mesh = jax.make_mesh((4,), ("model",), devices=jax.devices()[:4])
+    rng = np.random.default_rng(5)
+    A_np = rng.standard_normal((32, 64)).astype(np.float32)
+    A_np *= np.kron(rng.random((4, 8)) < 0.5, np.ones((8, 8)))
+    out = {}
+    for backend in ("dense_scan", "block_sparse"):
+        for out_sharded, m in ((False, 2), (True, 4)):
+            p = make_plan(m, 1, num_workers=4, seed=4)
+            A = jnp.asarray(A_np[:, :16 * m])
+            B = jnp.asarray(rng.standard_normal((32, 16)), jnp.float32)
+            kw = ({"a_sparse": dense_to_block_ell(A_np[:, :16 * m], 8)}
+                  if backend == "block_sparse" else {})
+            op = from_plan(CodedMatmulConfig(backend=backend,
+                                             out_sharded=out_sharded),
+                           p).bind(mesh)
+            masks = _masks(op)
+            fresh = []
+            for mask in masks:
+                STAGED.clear()
+                fresh.append(np.asarray(op.with_survivors(mask)(A, B, **kw)))
+            STAGED.clear()
+            op(A, B, **kw).block_until_ready()
+            before = obs.RECORDER.counters().get("program_hits", 0)
+            kept = [np.asarray(op.with_survivors(mask)(A, B, **kw))
+                    for mask in masks]
+            hits = obs.RECORDER.counters().get("program_hits", 0) - before
+            out[f"{backend}-{out_sharded}"] = {
+                "masks": len(masks),
+                "chunked": any(m is not None and m.ndim == 2 for m in masks),
+                "equal": [bool(np.array_equal(a, b))
+                          for a, b in zip(kept, fresh)],
+                "hits": hits}
+            STAGED.clear()
+    return out
+
+
+@pytest.fixture(scope="module")
+def cached_vs_fresh():
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    proc = subprocess.run(
+        [sys.executable, "-c", _FOUR_DEVICES,
+         str(pathlib.Path(__file__).resolve().parent)],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    import json
+
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("backend", ["dense_scan", "block_sparse"])
+@pytest.mark.parametrize("out_sharded", [False, True])
+def test_a_kept_program_equals_a_fresh_one_under_every_mask(
+        cached_vs_fresh, backend, out_sharded):
+    """One staged program serves None, every decodable single loss and a
+    chunk-progress mask, bit for bit as a program staged afresh for each."""
+    got = cached_vs_fresh[f"{backend}-{out_sharded}"]
+    assert got["masks"] >= (2 if out_sharded else 3) and got["chunked"], got
+    assert all(got["equal"]), got
+    assert got["hits"] == got["masks"], got
+
+
+def _hit_counts(monkeypatch, calls) -> list:
+    """Each call's product: "hit" or "miss", from a fresh recorder."""
+    from repro import obs
+
+    monkeypatch.setattr(obs, "RECORDER", obs.Recorder())
+    for call in calls:
+        call().block_until_ready()
+    return ["hit" if "program_hits" in s.counts else "miss"
+            for s in obs.records() if s.name == obs.PRODUCT]
+
+
+@pytest.mark.parametrize("change", ["b_width", "pack", "mesh", "layout",
+                                    "out_dtype"])
+def test_a_new_geometry_misses_once_then_hits(monkeypatch, change):
+    from repro.runtime import pack_cache
+
+    p = make_plan(1, 1, num_workers=1, max_degree=1, seed=3)
+    rng = np.random.default_rng(4)
+    A_np = rng.standard_normal((24, 16)).astype(np.float32)
+    A = jnp.asarray(A_np)
+    B = jnp.asarray(rng.standard_normal((24, 8)), jnp.float32)
+    ell = dense_to_block_ell(A_np, block_size=8)
+    mesh = jax.make_mesh((1,), ("model",), devices=jax.devices()[:1])
+    op = from_plan(CodedMatmulConfig(backend="block_sparse"), p).bind(mesh)
+    kw = kw2 = {"a_sparse": ell}
+    B2, op2 = B, op
+    if change == "b_width":
+        B2 = jnp.concatenate([B, B], axis=1)
+    elif change == "pack":
+        kw2 = {"a_sparse": dense_to_block_ell(A_np, block_size=8)}
+    elif change == "mesh":
+        op2 = from_plan(CodedMatmulConfig(backend="block_sparse",
+                                          axis_name="workers"), p).bind(
+            jax.make_mesh((1,), ("workers",), devices=jax.devices()[:1]))
+    else:
+        setting = ({"out_sharded": True} if change == "layout"
+                   else {"out_dtype": "bfloat16"})
+        op2 = from_plan(CodedMatmulConfig(backend="block_sparse", **setting),
+                        p).bind(mesh)
+    pack_cache.clear()
+    try:
+        got = _hit_counts(monkeypatch, [lambda: op(A, B, **kw)] * 2
+                          + [lambda: op2(A, B2, **kw2)] * 2
+                          + [lambda: op(A, B, **kw)])
+    finally:
+        pack_cache.clear()
+    assert got == ["miss", "hit", "miss", "hit", "hit"]
+
+
+def test_the_pack_caches_clear_and_eviction_release_device_copies():
+    """A pack's device copy goes with the pack: an evicted pack that no op
+    holds frees it at once; a cleared cache leaves nothing staged."""
+    from repro.core.coded_matmul import STAGED
+    from repro.runtime import pack_cache
+
+    p = make_plan(1, 1, num_workers=1, max_degree=1, seed=3)
+    rng = np.random.default_rng(6)
+    A_np = rng.standard_normal((24, 16)).astype(np.float32)
+    A = jnp.asarray(A_np)
+    B = jnp.asarray(rng.standard_normal((24, 8)), jnp.float32)
+    op = from_plan(CodedMatmulConfig(backend="block_sparse"), p).bind(
+        jax.make_mesh((1,), ("model",), devices=jax.devices()[:1]))
+    pack_cache.clear()
+    try:
+        ells = [dense_to_block_ell(A_np, block_size=8)
+                for _ in range(pack_cache.GLOBAL.max_entries + 1)]
+        for ell in ells:
+            op(A, B, a_sparse=ell).block_until_ready()
+        assert pack_cache.cache_stats()["evictions"] == 1
+        assert STAGED.stats() == {
+            "programs": 1, "device_packs": pack_cache.GLOBAL.max_entries}
+        pack_cache.clear()
+        assert STAGED.stats() == {"programs": 0, "device_packs": 0}
+    finally:
+        pack_cache.clear()
+
+
+def _packs(count: int, p) -> list:
+    """``count`` tile packs of distinct 16 x 8 operands."""
+    from repro.core.coded_matmul import pack_worker_tiles
+
+    rng = np.random.default_rng(8)
+    return [pack_worker_tiles(dense_to_block_ell(
+        rng.standard_normal((16, 8)).astype(np.float32), block_size=8), p)
+        for _ in range(count)]
+
+
+def test_the_staging_caches_stay_bounded():
+    from repro.core.coded_matmul import StagedPrograms
+
+    p = make_plan(1, 1, num_workers=1, max_degree=1, seed=3)
+    staged = StagedPrograms(max_entries=2)
+    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    packs = _packs(3, p)
+    for k, pack in enumerate(packs):
+        staged.program(("geometry", k), lambda x: x + 1)
+        assert staged.device_pack(pack, sharding)[1] is True     # put
+    assert staged.stats() == {"programs": 2, "device_packs": 2}
+    assert staged.program(("geometry", 0), lambda x: x)[1] is False  # evicted
+    assert staged.device_pack(packs[0], sharding)[1] is True         # evicted
+    copies, fresh = staged.device_pack(packs[2], sharding)           # kept
+    assert fresh is False
+    np.testing.assert_array_equal(np.asarray(copies[0]), packs[2].vals)
+    np.testing.assert_array_equal(np.asarray(copies[1]), packs[2].src)
+    # a pack that dies takes its copy with it, at once
+    del packs[2], pack
+    assert staged.stats()["device_packs"] == 1
+
+
+def test_the_staging_caches_hold_under_threads():
+    """More threads than cores, switching often, over more keys and packs
+    than the caches keep: each lookup returns what was kept for its own key
+    and pack, and the bound holds."""
+    import os
+    import sys
+    import threading
+
+    from repro.core.coded_matmul import StagedPrograms
+
+    p = make_plan(1, 1, num_workers=1, max_degree=1, seed=3)
+    staged = StagedPrograms(max_entries=3)
+    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    packs = _packs(5, p)
+    programs = [lambda x: x for _ in packs]
+    wrong = []
+
+    def work(t):
+        for i in range(300):
+            k = (t + i) % len(packs)
+            try:
+                jitted, _ = staged.program(("geometry", k), programs[k])
+                copies, _ = staged.device_pack(packs[k], sharding)
+            except Exception as e:      # a lost update, reported below
+                wrong.append((t, i, repr(e)))
+                continue
+            if jitted.__wrapped__ is not programs[k] or not (
+                    np.array_equal(np.asarray(copies[0]), packs[k].vals)
+                    and np.array_equal(np.asarray(copies[1]), packs[k].src)):
+                wrong.append((t, i))
+
+    threads = [threading.Thread(target=work, args=(t,))
+               for t in range(min((os.cpu_count() or 1) + 2, 16))]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not wrong
+    assert staged.stats() == {"programs": 3, "device_packs": 3}
+
+
+@pytest.mark.parametrize("out_sharded", [False, True])
+def test_under_an_outer_jit_nothing_is_kept(out_sharded):
+    """A caller jitting around ``apply`` with ``pack=`` stages as before: the
+    product is right, and neither cache holds anything, let alone a tracer."""
+    from repro.core.coded_matmul import STAGED
+    from repro.runtime import pack_cache
+
+    p = make_plan(1, 1, num_workers=1, max_degree=1, seed=3)
+    rng = np.random.default_rng(9)
+    A_np = rng.standard_normal((24, 16)).astype(np.float32)
+    B_np = rng.standard_normal((24, 8)).astype(np.float32)
+    op = from_plan(CodedMatmulConfig(backend="block_sparse",
+                                     out_sharded=out_sharded), p).bind(
+        jax.make_mesh((1,), ("model",), devices=jax.devices()[:1]))
+    pack = op.pack_for(dense_to_block_ell(A_np, block_size=8),
+                       use_cache=False)
+    pack_cache.clear()
+    try:
+        C = jax.jit(lambda a, b: op.apply(a, b, pack=pack))(
+            jnp.asarray(A_np), jnp.asarray(B_np))
+        np.testing.assert_allclose(np.asarray(C), A_np.T @ B_np,
+                                   rtol=1e-5, atol=1e-5)
+        assert STAGED.stats() == {"programs": 0, "device_packs": 0}
+        # and a concrete call after it keeps device arrays, not tracers
+        op.apply(jnp.asarray(A_np), jnp.asarray(B_np), pack=pack)
+        _, copies = next(iter(STAGED._packs.values()))
+        assert len(copies) == 2 and not any(
+            isinstance(a, jax.core.Tracer) for a in copies)
+    finally:
+        pack_cache.clear()
